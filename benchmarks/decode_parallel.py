@@ -270,6 +270,9 @@ def run(fast: bool = True, chaos_seed: int | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     import argparse
 
     ap = argparse.ArgumentParser()

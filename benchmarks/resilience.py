@@ -171,6 +171,9 @@ def run(fast: bool = True, chaos_seed: int = 0) -> dict:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     import argparse
 
     ap = argparse.ArgumentParser()

@@ -50,4 +50,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     main()

@@ -17,8 +17,12 @@ halves of that claim on CPU:
   * **byte identity** — every sweep point asserts the mesh-path frame equals
     the host-partition oracle's frame, each shard's subframe equals a
     single-device engine run on that shard's slice, the v4 container
-    round-trips through the serial oracle, and `read_range` spans crossing
-    shard boundaries return the right bytes.
+    round-trips through the mesh decoder and the serial oracle, and
+    `read_range` spans crossing shard boundaries return the right bytes.
+
+The checks live in `fabric_check`, which runs in the calling process on
+whatever devices the mesh holds; ``chip_smoke.py --chips 4`` calls it on
+four TPU chips.
 
 Writes experiments/benchmarks/sharded_fabric.json, mirrored to
 BENCH_sharded_fabric.json at the repo root.
@@ -29,6 +33,9 @@ import json
 import os
 import subprocess
 import sys
+import time
+
+import numpy as np
 
 if __package__ in (None, ""):        # `python benchmarks/sharded_fabric.py`
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -40,94 +47,150 @@ DEVICE_COUNTS = (1, 2, 4, 8)
 BLOCKS_PER_SHARD = 2
 REPEAT = 2
 
-# Runs in a fresh interpreter per device count; prints one RESULT: JSON line.
-_CHILD = r"""
-import json
-import os
-import sys
-import time
+def weak_scaling_data(n_blocks: int) -> bytes:
+    """``n_blocks`` x 64 KB, each block 2/3 compressible structure and 1/3
+    incompressible bytes (seeded)."""
+    from repro.core.lz4_types import MAX_BLOCK
 
-import numpy as np
+    rng = np.random.default_rng(7)
+    parts = []
+    for i in range(n_blocks):
+        parts.append((b"weak scaling shard %d " % i) * (2 * MAX_BLOCK // 63))
+        parts.append(rng.integers(0, 256, MAX_BLOCK // 3, np.uint8).tobytes())
+    return b"".join(parts)[: n_blocks * MAX_BLOCK]
 
-from repro.core import FrameReader, LZ4Engine, decode_frame_serial, frame_info
-from repro.core.lz4_types import MAX_BLOCK
-from repro.distributed import fabric
-from repro.distributed.sharding import make_mesh_compat
 
-import jax
+def fabric_check(mesh, data: bytes, repeat: int = 0) -> dict:
+    """Compress ``data`` on ``mesh`` and check the fabric against its oracles.
 
-devices = int(os.environ["FABRIC_BENCH_DEVICES"])
-blocks_per_shard = int(os.environ["FABRIC_BENCH_BPS"])
-repeat = int(os.environ["FABRIC_BENCH_REPEAT"])
-assert len(jax.devices()) == devices
+    Runs in the calling process, on whatever devices ``mesh`` holds (fake CPU
+    devices in the weak-scaling sweep, chips in ``chip_smoke.py --chips 4``).
+    Checks, each a boolean in the result:
 
-n_blocks = devices * blocks_per_shard
-rng = np.random.default_rng(7)
-parts = []
-for i in range(n_blocks):
-    # 2/3 compressible structure, 1/3 incompressible per block
-    parts.append((b"weak scaling shard %d " % i) * (2 * MAX_BLOCK // 63))
-    parts.append(rng.integers(0, 256, MAX_BLOCK // 3, np.uint8).tobytes())
-data = b"".join(parts)[: n_blocks * MAX_BLOCK]
+      * the mesh frame equals the host-partition oracle
+        (``LZ4Engine(shards=S)``) byte for byte;
+      * every `fabric.shard_subframe` equals a single-device engine's frame
+        of that shard's slice;
+      * ``LZ4DecodeEngine(mesh=mesh)`` and the serial oracle both decode the
+        frame back to ``data``; a `read_range` across the first shard
+        boundary returns the right bytes;
+      * the sharded compress dispatch takes its operands and returns its
+        results on ``S`` distinct devices (from the compiled program's
+        input shardings and the result arrays' shards).
 
-mesh = make_mesh_compat((devices,), ("data",))
-eng = LZ4Engine(mesh=mesh)
-assert eng.shards == devices
+    ``first_compress_s`` is the first call (compilation included) and
+    ``compress_s`` the best of ``repeat`` more (None when 0).
+    """
+    import jax
+    import jax.numpy as jnp
 
-frame = eng.compress(data)  # warmup (jit compile)
-best = float("inf")
-for _ in range(repeat):
+    from repro.core import (FrameReader, LZ4DecodeEngine, LZ4Engine,
+                            decode_frame_serial, frame_info)
+    from repro.core.jax_compressor import _PAD
+    from repro.core.lz4_types import MAX_BLOCK
+    from repro.distributed import fabric
+
+    eng = LZ4Engine(mesh=mesh)
+    S = eng.shards
     t0 = time.perf_counter()
     frame = eng.compress(data)
-    best = min(best, time.perf_counter() - t0)
+    first = time.perf_counter() - t0
+    warm = None
+    for _ in range(repeat):
+        t0 = time.perf_counter()
+        frame = eng.compress(data)
+        dt = time.perf_counter() - t0
+        warm = dt if warm is None else min(warm, dt)
+    dispatches = eng.stats.dispatches
 
-# -- byte-identity checks (the acceptance criteria, not just timing) --------
-info = frame_info(frame)
-assert info["version"] == 4 and info["shard_count"] == devices
-oracle = LZ4Engine(shards=devices).compress(data)
-identical_to_oracle = frame == oracle
-single = LZ4Engine()
-chunks = [data[i: i + MAX_BLOCK] for i in range(0, len(data), MAX_BLOCK)]
-per_shard_identical = all(
-    fabric.shard_subframe(frame, sl.shard) == single.compress(
-        b"".join(chunks[sl.start: sl.stop]))
-    for sl in fabric.partition_blocks(len(chunks), devices))
-roundtrip_ok = decode_frame_serial(frame) == data
-r = FrameReader(frame)
-b = blocks_per_shard * MAX_BLOCK  # first shard boundary
-cross_read_ok = (devices == 1 or
-                 r.read_range(b - 64, 128) == data[b - 64: b + 64])
+    info = frame_info(frame)
+    chunks = [data[i: i + MAX_BLOCK] for i in range(0, len(data), MAX_BLOCK)]
+    single = LZ4Engine()
+    per_shard_identical = all(
+        fabric.shard_subframe(frame, sl.shard) == single.compress(
+            b"".join(chunks[sl.start: sl.stop]))
+        for sl in fabric.partition_blocks(len(chunks), S))
+    dec = LZ4DecodeEngine(mesh=mesh)
+    t0 = time.perf_counter()
+    mesh_decode_ok = dec.decode(frame) == data
+    decode_s = time.perf_counter() - t0
+    b = fabric.partition_blocks(len(chunks), S)[0].stop * MAX_BLOCK
+    cross_read_ok = (S == 1 or b >= len(data) or
+                     FrameReader(frame).read_range(b - 64, 128)
+                     == data[b - 64: b + 64])
 
-print("RESULT:" + json.dumps({
-    "devices": devices,
-    "blocks": n_blocks,
-    "bytes_in": len(data),
-    "frame_bytes": len(frame),
-    "compress_s": round(best, 4),
-    "compress_mb_s": round(len(data) / best / 1e6, 3),
-    "dispatches": eng.stats.dispatches,
-    "identical_to_host_oracle": identical_to_oracle,
-    "per_shard_identical_to_single_device": per_shard_identical,
-    "serial_roundtrip_ok": roundtrip_ok,
-    "cross_shard_read_range_ok": cross_read_ok,
-}))
-"""
+    # The engine's own compiled dispatch (cached per config), run once more
+    # on an empty stack to read where its operands and results live.
+    fn = fabric._sharded_compress_compiled(
+        eng.mesh, tuple(eng.shard_axes), eng.hash_bits, eng.max_match,
+        eng.pws, eng.use_pallas, eng.scan_impl, eng.candidate_impl)
+    stack = np.zeros((S, MAX_BLOCK + _PAD), np.uint8)
+    ns = np.zeros((S,), np.int32)
+    in_shardings = fn.lower(stack, ns).compile().input_shardings[0]
+    operand_devices = {d.id for sh in in_shardings for d in sh.device_set}
+    res = jax.block_until_ready(fn(jnp.asarray(stack), jnp.asarray(ns)))
+    result_devices = {s.device.id for r in res for s in r.addressable_shards}
+
+    return {
+        "devices": S,
+        "platform": jax.devices()[0].platform,
+        "blocks": len(chunks),
+        "bytes_in": len(data),
+        "frame_bytes": len(frame),
+        "first_compress_s": first,
+        "compress_s": warm,
+        "decode_s": decode_s,
+        "dispatches": dispatches,
+        "operand_devices": sorted(operand_devices),
+        "result_devices": sorted(result_devices),
+        "frame_version_4": info["version"] == 4 and info["shard_count"] == S,
+        "identical_to_host_oracle": frame == LZ4Engine(shards=S).compress(data),
+        "per_shard_identical_to_single_device": per_shard_identical,
+        "mesh_decode_ok": mesh_decode_ok,
+        "serial_roundtrip_ok": decode_frame_serial(frame) == data,
+        "cross_shard_read_range_ok": cross_read_ok,
+        "spans_all_devices": (len(operand_devices) == S
+                              and len(result_devices) == S),
+    }
+
+
+CHECKS = ("frame_version_4", "identical_to_host_oracle",
+          "per_shard_identical_to_single_device", "mesh_decode_ok",
+          "serial_roundtrip_ok", "cross_shard_read_range_ok",
+          "spans_all_devices")
+
+
+def _child() -> None:
+    """One sweep point, in a fresh interpreter with N fake CPU devices."""
+    import jax
+
+    from repro.distributed.sharding import make_mesh
+
+    devices = int(os.environ["FABRIC_BENCH_DEVICES"])
+    assert len(jax.devices()) == devices
+    data = weak_scaling_data(devices * int(os.environ["FABRIC_BENCH_BPS"]))
+    pt = fabric_check(make_mesh((devices,), ("data",)), data,
+                      repeat=int(os.environ["FABRIC_BENCH_REPEAT"]))
+    pt["compress_mb_s"] = round(len(data) / pt["compress_s"] / 1e6, 3)
+    print("RESULT:" + json.dumps(pt))
 
 
 def _run_point(devices: int) -> dict:
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
     env = dict(os.environ)
     env.update({
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
         "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": os.pathsep.join(
-            [os.path.join(os.path.dirname(__file__), "..", "src"),
+            [os.path.join(root, "src"), root,
              env.get("PYTHONPATH", "")]).rstrip(os.pathsep),
         "FABRIC_BENCH_DEVICES": str(devices),
         "FABRIC_BENCH_BPS": str(BLOCKS_PER_SHARD),
         "FABRIC_BENCH_REPEAT": str(REPEAT),
     })
-    proc = subprocess.run([sys.executable, "-c", _CHILD], env=env,
-                          capture_output=True, text=True, timeout=900)
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmarks.sharded_fabric", "--child"],
+        env=env, cwd=root, capture_output=True, text=True, timeout=900)
     if proc.returncode != 0:
         raise RuntimeError(
             f"fabric bench child (devices={devices}) failed:\n"
@@ -140,9 +203,7 @@ def run() -> dict:
     points = []
     for devices in DEVICE_COUNTS:
         pt = _run_point(devices)
-        for check in ("identical_to_host_oracle",
-                      "per_shard_identical_to_single_device",
-                      "serial_roundtrip_ok", "cross_shard_read_range_ok"):
+        for check in CHECKS:
             assert pt[check], f"devices={devices}: {check} failed"
         points.append(pt)
         print(f"[sharded_fabric] devices={devices} "
@@ -180,4 +241,10 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
-    run()
+    from repro import compile_cache
+
+    compile_cache.enable()
+    if sys.argv[1:] == ["--child"]:
+        _child()
+    else:
+        run()

@@ -47,6 +47,9 @@ def run(fast: bool = True) -> dict:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     import json
 
     print(json.dumps(run(), indent=1))

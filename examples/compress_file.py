@@ -27,6 +27,9 @@ def compress_report(engine: LZ4Engine, name: str, data: bytes):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("path", nargs="?")
     ap.add_argument("--entries", type=int, default=256)

@@ -27,7 +27,10 @@ from repro.core import (
     frame_info,
     plan_size,
 )
+from repro import compile_cache
 from repro.core.cycle_model import ours_throughput
+
+compile_cache.enable()
 
 # --- some compressible data -------------------------------------------------
 rng = np.random.default_rng(0)

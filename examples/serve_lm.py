@@ -11,6 +11,9 @@ from repro.models import lm
 from repro.serving.engine import Request, ServingEngine, offload_cache, restore_cache
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     cfg = get_config("gemma2-9b").reduced()
     rng = np.random.default_rng(0)
     with use_mesh(single_device_mesh()):

@@ -11,6 +11,9 @@ import sys
 from repro.launch.train import main as train_main
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--steps", type=int, default=None)

@@ -39,11 +39,16 @@ def _text_like(rng: np.random.Generator, size: int) -> bytes:
     ranks = np.arange(1, len(_WORDS) + 1, dtype=np.float64)
     probs = 1.0 / ranks
     probs /= probs.sum()
+    # What `rng.choice(len(_WORDS), p=probs)` computes per call (one uniform
+    # draw against the normalized CDF), with the CDF built once: the same
+    # bytes for every seed, several times faster.
+    cdf = probs.cumsum()
+    cdf /= cdf[-1]
     out = []
     total = 0
     sentence_len = 0
-    while total < size:
-        w = _WORDS[rng.choice(len(_WORDS), p=probs)]
+    while total <= size:  # the join below is total - 1 long
+        w = _WORDS[int(cdf.searchsorted(rng.random(), side="right"))]
         if sentence_len == 0:
             w = w.capitalize()
         out.append(w)
@@ -62,7 +67,7 @@ def _code_like(rng: np.random.Generator, size: int) -> bytes:
     idents = [f"var_{i}" for i in range(40)] + [f"fn_{i}" for i in range(20)]
     lines = []
     total = 0
-    while total < size:
+    while total <= size:  # the join below is total - 1 long
         kind = rng.random()
         if kind < 0.25:
             ln = f"{rng.choice(_C_KEYWORDS)} {rng.choice(idents)} = {rng.integers(0, 1000)};"
@@ -85,7 +90,7 @@ def _records_like(rng: np.random.Generator, size: int) -> bytes:
     out = []
     total = 0
     rec = 0
-    while total < size:
+    while total <= size:  # the join below is total - 1 long
         rec += 1
         for f in fields:
             words = " ".join(rng.choice(_WORDS, size=rng.integers(2, 7)))

@@ -23,6 +23,8 @@ embarrassingly parallel (Sitaridi et al., arXiv 1606.00519):
                    forking a process with live JAX threads is officially
                    discouraged (workers never touch JAX, and only the pool
                    fork happens, but create the engine early if you use it).
+                   Checked under a parent that holds a TPU v5e chip: the
+                   forked workers decode correctly (JAX warns at the fork).
       "device"   — phase two runs INSIDE jit: host planning
                    (`plan_block_fast` -> `to_device_plan`) stacks a
                    micro-batch of fixed-shape `DevicePlan`s and ONE

@@ -138,6 +138,27 @@ def _slice_payload(out: np.ndarray, j: int, size: int) -> bytes:
     return out[j, :size].tobytes()
 
 
+# The sliced drain fetches each row's payload through one jitted slice whose
+# length is `size` rounded up to this quantum.  An eager ``out[j, :size]``
+# compiles a program per distinct (row, size), which costs a fraction of a
+# second each on a TPU and dominated the write path there; rounding bounds
+# the programs to ~17 lengths per micro-batch shape.
+FETCH_QUANTUM = 4096
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _row_prefix(out, row, length: int):
+    return jax.lax.dynamic_slice_in_dim(out, row, 1)[0, :length]
+
+
+def fetch_row_prefix(out_dev, row: int, size: int) -> tuple[bytes, int]:
+    """Row ``row``'s first ``size`` bytes of a device (M, out_cap) emit
+    buffer, and the number of bytes moved to the host to get them."""
+    length = min(-(-size // FETCH_QUANTUM) * FETCH_QUANTUM, out_dev.shape[1])
+    buf = np.asarray(_row_prefix(out_dev, row, length))
+    return buf[:size].tobytes(), length
+
+
 class LZ4Engine:
     """Batched LZ4 compression engine (the paper's combined scheme, S1+S2).
 
@@ -216,11 +237,12 @@ class LZ4Engine:
         # and emit on host via emit_block (the bit-identity oracle path).
         self.device_emit = device_emit
         # drain="sliced" (device_emit only): two-step fetch — size scalars
-        # first, then exactly `size` bytes per block, and NOTHING for
-        # blocks bound for raw passthrough — so host_bytes is the exact
-        # compressed payload.  "full" fetches the whole padded (M, out_cap)
-        # buffer per micro-batch in one transfer (fewer, larger copies; the
-        # pre-two-step behaviour, kept measurable in benchmarks).
+        # first, then `size` bytes per block rounded up to FETCH_QUANTUM,
+        # and NOTHING for blocks bound for raw passthrough — so host_bytes
+        # is the compressed payload plus under 4 KB per block.  "full"
+        # fetches the whole padded (M, out_cap) buffer per micro-batch in
+        # one transfer (fewer, larger copies; the pre-two-step behaviour,
+        # kept measurable in benchmarks).
         self.drain = drain
         # content_crc=True: stamp a whole-object CRC32 trailer on every
         # frame (version 5) on top of the per-block checksums — full-frame
@@ -353,11 +375,12 @@ class LZ4Engine:
             occupancy.dec()
 
     def _fetch_sliced(self, out_dev, j: int, size: int, st: EngineStats) -> bytes:
-        """Slice-fetch exactly `size` compressed bytes of row j (the device
-        slice executes on-device; only the payload crosses to host)."""
+        """Slice-fetch row j's `size` compressed bytes (the device slice
+        executes on-device; only the payload, rounded up to
+        `FETCH_QUANTUM`, crosses to host)."""
         with self._sp("compress.drain", bytes=size):
-            data = np.asarray(out_dev[j, :size]).tobytes()
-        st.host_bytes += size
+            data, moved = fetch_row_prefix(out_dev, j, size)
+        st.host_bytes += moved
         return data
 
     def _drain(self, batch: list[bytes], res, st: EngineStats):

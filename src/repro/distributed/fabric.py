@@ -23,10 +23,11 @@ Two execution paths, bit-identical by construction:
   * **mesh path** (`mesh` with >1 shard): one global
     ``(S*r, MAX_BLOCK+_PAD)`` stack per step, `shard_map` splits it along
     the shard axes, every shard compresses its ``r`` rows concurrently,
-    and the two-step sliced drain fetches exactly the compressed payload
-    bytes.  Decode mirrors it: host planning (`plan_block_fast` ->
-    `to_device_plan`) stacks fixed-shape `DevicePlan`s per shard and one
-    `shard_map`(vmap(`decode_gather`)) dispatch resolves every block.
+    and the two-step sliced drain fetches the compressed payload bytes
+    (rounded up to `engine.FETCH_QUANTUM`).  Decode mirrors it: host
+    planning (`plan_block_fast` -> `to_device_plan`) stacks fixed-shape
+    `DevicePlan`s per shard and one `shard_map`(vmap(`decode_gather`))
+    dispatch resolves every block.
   * **host path** (no mesh, or a 1-shard mesh): each shard's slice runs
     through a plain single-device `LZ4Engine` worker sequentially — the
     ORACLE the mesh path is pinned against, and what keeps the v4 writer
@@ -55,11 +56,10 @@ from jax.sharding import PartitionSpec as P
 from repro import obs
 from repro.core.decode_plan import execute_plan
 from repro.core.decoder import LZ4FormatError
+from repro.core.engine import fetch_row_prefix
 from repro.core.frame import FrameFormatError, block_crc, check_block, encode_frame, frame_info
 from repro.core.jax_compressor import _PAD, compress_block_bytes
 from repro.core.lz4_types import MAX_BLOCK, pad_pow2_count
-
-from .sharding import shard_map_compat
 
 __all__ = [
     "ShardSlice",
@@ -129,7 +129,7 @@ def _sharded_compress_compiled(mesh, shard_axes, hash_bits, max_match, pws,
         candidate_impl=candidate_impl,
     )
     spec = P(shard_axes)
-    sm = shard_map_compat()(
+    sm = jax.shard_map(
         jax.vmap(fn), mesh=mesh,
         in_specs=(spec, spec), out_specs=(spec, spec),
         check_vma=False,
@@ -138,10 +138,10 @@ def _sharded_compress_compiled(mesh, shard_axes, hash_bits, max_match, pws,
 
 
 def _fetch_payload(st, sp, out_dev, row: int, size: int) -> bytes:
-    """Slice-fetch exactly ``size`` compressed bytes of one stacked row."""
+    """Slice-fetch ``size`` compressed bytes of one stacked row."""
     with sp("compress.drain", bytes=size):
-        data = np.asarray(out_dev[row, :size]).tobytes()
-    st.host_bytes += size
+        data, moved = fetch_row_prefix(out_dev, row, size)
+    st.host_bytes += moved
     return data
 
 
@@ -321,7 +321,7 @@ def _sharded_decode_compiled(mesh, shard_axes, out_cap, rounds, use_pallas):
     fn = functools.partial(decode_gather, out_cap=out_cap, rounds=rounds,
                            use_pallas=use_pallas)
     spec = P(shard_axes)
-    sm = shard_map_compat()(
+    sm = jax.shard_map(
         jax.vmap(fn), mesh=mesh,
         in_specs=(spec,) * 9, out_specs=spec,
         check_vma=False,
@@ -343,7 +343,7 @@ def _sharded_plan_decode_compiled(mesh, shard_axes, out_cap, max_lit,
                            max_match=max_match, rounds=rounds,
                            use_pallas=use_pallas, compute_crc=False)
     spec = P(shard_axes)
-    sm = shard_map_compat()(
+    sm = jax.shard_map(
         jax.vmap(fn), mesh=mesh,
         in_specs=(spec,) * 3, out_specs=(spec, spec, spec),
         check_vma=False,

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sharding import get_mesh, shard_map_compat as _shard_map_compat
+from .sharding import get_mesh
 
 
 def pipeline_apply(stage_fn, stage_params, x, *, axis: str = "pod", n_micro: int | None = None):
@@ -76,7 +76,7 @@ def pipeline_apply(stage_fn, stage_params, x, *, axis: str = "pod", n_micro: int
         )
         return outbuf
 
-    out = _shard_map_compat()(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P()),

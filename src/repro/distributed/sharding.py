@@ -17,7 +17,7 @@ from typing import Any
 import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 
 _STATE: dict[str, Any] = {"mesh": None}
 
@@ -41,35 +41,15 @@ def use_mesh(mesh: Mesh):
         _STATE["mesh"] = prev
 
 
-def shard_map_compat():
-    """jax.shard_map across jax versions (one shim, shared by all callers)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    # Older jax: shard_map lives in jax.experimental and the check_vma
-    # kwarg is spelled check_rep.
-    from jax.experimental.shard_map import shard_map as legacy
-
-    def fn(*args, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return legacy(*args, **kw)
-
-    return fn
-
-
-def make_mesh_compat(shape, axes, devices=None) -> Mesh:
-    # axis_types (and jax.sharding.AxisType) only exist on newer jax; Auto is
-    # the default there, so omitting it on older versions is equivalent.
-    kw = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kw["axis_types"] = (axis_type.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """`jax.make_mesh` with every axis Auto (jax defaults to Explicit), so
+    sharding is propagated by the compiler from the rules below."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def single_device_mesh() -> Mesh:
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def batch_axes(mesh: Mesh | None = None, pure_dp: bool = False):

@@ -13,6 +13,10 @@ carries `crc32_bytes`, the in-graph slice-by-8 CRC-32 that keeps verified
 device restores free of content fetches.
 
 Layout per kernel: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-dispatch wrappers), ref.py (pure-jnp oracles).  Validated with interpret=True
-on CPU; the TARGET is TPU v5e (see module docstrings for the Mosaic mapping).
+dispatch wrappers), ref.py (pure-jnp oracles).  backend.py resolves each
+kernel's execution mode from the JAX backend: interpreted off the TPU (how
+the CPU tests validate them), compiled on a TPU.  For TPU v5e only fibhash
+compiles; the other five read at data-dependent indices with 1-D gathers,
+which Mosaic refuses, so with ``use_pallas=True`` they raise
+`backend.PallasUnsupportedError` there (tests/test_tpu_compile.py).
 """

@@ -19,8 +19,9 @@ depth bucket, worst case ceil(log2(MAX_BLOCK)) = 16, so even pathological
 RLE chains (depth 65535) resolve with no data-dependent control flow and no
 host fallback.
 
-The gathers are `jnp.take`, which Mosaic lowers to the TPU dynamic-gather
-unit (v4+); validated with interpret=True here.  The byte math is
+The gathers are 1-D `jnp.take`, which the TPU compiler refuses
+(backend.TPU_REFUSED): the kernel runs only in the interpreter, off the
+TPU.  The byte math is
 intentionally duplicated from kernels/ref.py `decode_gather_ref` (the jnp
 oracle): the two paths stay independent and are asserted bit-identical in
 tests/test_device_decode.py.
@@ -32,6 +33,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .backend import interpret_mode
 
 
 def _decode_wave_kernel(total_ref, blk_ref, lit_blk_ref, ptr_ref, out_ref, *,
@@ -46,7 +49,7 @@ def _decode_wave_kernel(total_ref, blk_ref, lit_blk_ref, ptr_ref, out_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("rounds", "interpret"))
 def decode_wave_pallas(block, lit_blk, ptr, total, rounds: int,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Resolve + materialize one block's decoded bytes on device.
 
     block   : (B,) int32 compressed-payload byte values (zero-padded)
@@ -57,7 +60,10 @@ def decode_wave_pallas(block, lit_blk, ptr, total, rounds: int,
 
     Returns (K,) int32 byte values (cast to uint8 at the ops.py boundary —
     int32 lanes keep the kernel on the VPU's native element type).
+    ``interpret=None`` resolves from the backend (`backend.interpret_mode`).
     """
+    if interpret is None:
+        interpret = interpret_mode("decode_wave")
     K = ptr.shape[0]
     B = block.shape[0]
     assert lit_blk.shape[0] == K, (lit_blk.shape, K)

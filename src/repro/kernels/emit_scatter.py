@@ -14,8 +14,9 @@ Memory layout (mirrors match_extend.py):
     on-chip buffers);
   * `seg` and the output are tiled by TILE positions;
   * the two data-dependent reads — per-sequence fields at `seg[k]` and input
-    literals at `anchor + r` — are `jnp.take`, which Mosaic lowers to the
-    TPU dynamic-gather unit (v4+); validated with interpret=True here.
+    literals at `anchor + r` — are 1-D `jnp.take` gathers, which the TPU
+    compiler refuses (backend.TPU_REFUSED): the kernel runs only in the
+    interpreter, off the TPU.
 
 The byte math is intentionally duplicated from kernels/ref.py
 `emit_bytes_ref` (the jnp oracle): the two paths stay independent and are
@@ -29,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import interpret_mode
 from .ref import (
     F_ANCHOR,
     F_HAS_MATCH,
@@ -53,7 +55,7 @@ def _emit_scatter_kernel(total_ref, block_ref, fields_ref, seg_ref, out_ref, *, 
     seg = seg_ref[...]
     k = base + jax.lax.iota(jnp.int32, tile)
 
-    # Gather the covering sequence's layout fields (dynamic-gather unit).
+    # Gather the covering sequence's layout fields.
     st = jnp.take(f[F_START], seg)
     anc = jnp.take(f[F_ANCHOR], seg)
     lit = jnp.take(f[F_LIT], seg)
@@ -79,7 +81,8 @@ def _emit_scatter_kernel(total_ref, block_ref, fields_ref, seg_ref, out_ref, *, 
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def emit_scatter_pallas(block, seg, fields, total, interpret: bool = True):
+def emit_scatter_pallas(block, seg, fields, total,
+                        interpret: bool | None = None):
     """Materialize the compressed block's bytes on device.
 
     block  : (B,) int32 input byte values (zeroed past the true length)
@@ -89,7 +92,10 @@ def emit_scatter_pallas(block, seg, fields, total, interpret: bool = True):
 
     Returns (K,) int32 byte values (cast to uint8 at the ops.py boundary —
     int32 lanes keep the kernel on the VPU's native element type).
+    ``interpret=None`` resolves from the backend (`backend.interpret_mode`).
     """
+    if interpret is None:
+        interpret = interpret_mode("emit_scatter")
     K = seg.shape[0]
     B = block.shape[0]
     S = fields.shape[1]

@@ -19,6 +19,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.lz4_types import HASH_PRIME
 
+from .backend import interpret_mode
+
 TILE = 2048  # positions per grid step; 8 vregs of int32
 
 
@@ -35,8 +37,14 @@ def _fibhash_kernel(b0_ref, b1_ref, b2_ref, b3_ref, w_ref, h_ref, *, hash_bits: 
 
 
 @functools.partial(jax.jit, static_argnames=("hash_bits", "interpret"))
-def fibhash_pallas(b0, b1, b2, b3, hash_bits: int = 8, interpret: bool = True):
-    """(P,) int32 shifted byte streams -> (word_i32, hash_i32), P % TILE == 0."""
+def fibhash_pallas(b0, b1, b2, b3, hash_bits: int = 8,
+                   interpret: bool | None = None):
+    """(P,) int32 shifted byte streams -> (word_i32, hash_i32), P % TILE == 0.
+
+    ``interpret=None`` resolves from the backend (`backend.interpret_mode`).
+    """
+    if interpret is None:
+        interpret = interpret_mode("fibhash")
     P = b0.shape[0]
     assert P % TILE == 0, f"P={P} must be a multiple of {TILE}"
     grid = (P // TILE,)

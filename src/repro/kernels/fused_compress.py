@@ -34,8 +34,10 @@ The LVT persists across grid steps as a revisited output block (constant
 index map — the standard Pallas accumulator pattern, initialized at step
 0), so one `pallas_call` covers all 32 tiles of a 64 KB block with zero
 intermediate HBM materializations; under vmap each block of a micro-batch
-gets its own table.  The data-dependent reads are `jnp.take` (TPU
-dynamic-gather unit, v4+); validated with interpret=True on CPU.
+gets its own table.  The data-dependent reads are 1-D `jnp.take`, and the
+per-tile block reads are unaligned slices; the TPU compiler refuses both
+(backend.TPU_REFUSED), so the kernel runs only in the interpreter, off the
+TPU.
 
 The jnp twin is `ref.fused_ref` (whole-block scatter formulation, pinned
 bit-identical to the `_candidates` sort oracle at the record level);
@@ -56,6 +58,8 @@ from repro.core.lz4_types import (
     MF_LIMIT,
     MIN_MATCH,
 )
+
+from .backend import interpret_mode
 
 TILE = 2048  # positions per grid step (matches fibhash/match_extend tiling)
 
@@ -138,17 +142,14 @@ def fused_compress_pallas(block, n, positions: int, hash_bits: int = 8,
                 B >= positions + max_match (the padded compressor block)
     n         : (1,) int32 true block length
     positions : static position count P; P % TILE == 0, TILE % pws == 0
-    interpret : None (default) compiles to Mosaic on a TPU backend and
-                falls back to the Pallas interpreter everywhere else, so
-                `use_pallas=True` actually reaches the hardware kernel on
-                TPU while CPU runs stay a correctness check.
+    interpret : None resolves from the backend (`backend.interpret_mode`)
 
     Returns ``(cand, lengths)``: (P,) int32 each — candidate position (-1
     where none/invalid) and full match length (0 where no valid match,
     else in [MIN_MATCH, max_match]), elementwise-equal to `ref.fused_ref`.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode("fused_compress")
     P = positions
     B = block.shape[0]
     E = 1 << hash_bits

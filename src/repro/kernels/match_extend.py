@@ -12,13 +12,16 @@ Memory layout:
     cache", Section IV-A).  Every grid step sees the whole block (BlockSpec
     maps all tiles to block 0) while candidate indices/outputs are tiled.
   * `block[p + 4 + j]` for a position tile is a *static* slice (p = base +
-    iota), emitted with pl.dslice on the scalar base — no gather.
+    iota), a contiguous `lax.dynamic_slice` at the tile base — no gather.
   * `block[cand + 4 + j]` is a genuine data-dependent read: candidates point
-    anywhere earlier in the block.  It is expressed as `jnp.take`, which
-    Mosaic lowers to the TPU dynamic-gather unit (v4+); in this container it
-    is validated with interpret=True.  This read is the paper's "data memory"
-    port in Fig. 5 — one read per position per j, exactly PWS x (L_max-4)
-    byte-compares per window, same as the hardware.
+    anywhere earlier in the block.  It is expressed as a 1-D `jnp.take`.
+    This read is the paper's "data memory" port in Fig. 5 — one read per
+    position per j, exactly PWS x (L_max-4) byte-compares per window, same
+    as the hardware.
+
+The TPU compiler refuses this kernel (the unaligned slice and the 1-D
+gather; backend.TPU_REFUSED), so it runs only in the interpreter, off the
+TPU, where the tests check it against its jnp twin.
 """
 from __future__ import annotations
 
@@ -29,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.lz4_types import LAST_LITERALS, MIN_MATCH
+
+from .backend import interpret_mode
 
 TILE = 2048
 
@@ -51,7 +56,7 @@ def _match_extend_kernel(
     for j in range(max_match - MIN_MATCH):
         # Static-offset slice of the block for the current positions...
         cur = jax.lax.dynamic_slice(blk, (base + MIN_MATCH + j,), (tile,))
-        # ...and a dynamic gather for the candidates (TPU dynamic-gather unit).
+        # ...and a data-dependent gather for the candidates.
         cnd = jnp.take(blk, jnp.clip(cand + MIN_MATCH + j, 0, B - 1), axis=0)
         prefix = prefix & (cur == cnd) & (j < max_extra)
         length = length + prefix.astype(jnp.int32)
@@ -59,14 +64,18 @@ def _match_extend_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("max_match", "interpret"))
-def match_extend_pallas(block, cand, valid, n, max_match: int = 36, interpret: bool = True):
+def match_extend_pallas(block, cand, valid, n, max_match: int = 36,
+                        interpret: bool | None = None):
     """Bounded match lengths for every position.
 
     block : (B,) int32, B >= P + max_match (padded); the full on-chip buffer
     cand  : (P,) int32 candidate positions, P % TILE == 0
     valid : (P,) bool
     n     : (1,) int32 true length
+    interpret : None resolves from the backend (`backend.interpret_mode`)
     """
+    if interpret is None:
+        interpret = interpret_mode("match_extend")
     P = cand.shape[0]
     B = block.shape[0]
     assert P % TILE == 0, f"P={P} must be a multiple of {TILE}"

@@ -1,8 +1,11 @@
 """jit'd wrappers around the Pallas kernels with pure-jnp fallback dispatch.
 
-`use_pallas` selects the Pallas path (interpret=True on CPU; on a real TPU the
-same call sites compile the Mosaic kernels).  The jnp fallback is the oracle
-in ref.py — both paths are interchangeable and tested for exact equality.
+`use_pallas` selects the Pallas path.  Each kernel resolves its execution
+mode from the backend (`backend.interpret_mode`): the interpreter off the
+TPU; on a TPU the compiled Mosaic kernel, or `PallasUnsupportedError` for a
+kernel the TPU compiler refuses (all but fibhash today).  The jnp fallback
+is the oracle in ref.py — both paths are interchangeable and tested for
+exact equality.
 """
 from __future__ import annotations
 

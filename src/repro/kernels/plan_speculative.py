@@ -26,9 +26,10 @@ fallback for well-formed streams.  The field math reproduces
 validator downstream (`kernels/ops.py` `plan_speculative`) rejects
 malformed streams with error codes identical to the host oracle's.
 
-The gathers are `jnp.take` and the chain union is a scatter-max
-(`.at[].max`), per the emit_scatter.py precedent; validated with
-interpret=True here.  The math is intentionally duplicated from
+The gathers are 1-D `jnp.take` and the chain union is a scatter-max
+(`.at[].max`), per the emit_scatter.py precedent; the TPU compiler refuses
+the gathers (backend.TPU_REFUSED), so the kernel runs only in the
+interpreter, off the TPU.  The math is intentionally duplicated from
 kernels/ref.py `plan_fields_ref` (the jnp oracle): the two paths stay
 independent and are asserted bit-identical in tests/test_plan_speculative.py.
 """
@@ -39,6 +40,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .backend import interpret_mode
 
 # Doubling depth of the chain-select pass: 2^16 hops covers any sequence
 # chain a 64 KB block can contain (headers are >= 3 bytes apart).
@@ -106,7 +109,7 @@ def _plan_spec_kernel(n_ref, blk_ref, start_ref, lit_start_ref, lit_len_ref,
 
 @functools.partial(jax.jit, static_argnames=("chain_rounds", "interpret"))
 def plan_spec_pallas(block, n, chain_rounds: int = CHAIN_ROUNDS,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Speculatively parse one block's candidate headers on device.
 
     block        : (B,) int32 compressed-payload byte values, zeroed past
@@ -118,8 +121,11 @@ def plan_spec_pallas(block, n, chain_rounds: int = CHAIN_ROUNDS,
     Returns seven (B,) int32 arrays (is_start, lit_start, lit_len, ls_end,
     off, mlen, flags) — field semantics documented on kernels/ref.py
     `plan_fields_ref`, validation/compaction in kernels/ops.py
-    `plan_speculative`.
+    `plan_speculative`.  ``interpret=None`` resolves from the backend
+    (`backend.interpret_mode`).
     """
+    if interpret is None:
+        interpret = interpret_mode("plan_spec")
     B = block.shape[0]
     return pl.pallas_call(
         functools.partial(_plan_spec_kernel, chain_rounds=chain_rounds),

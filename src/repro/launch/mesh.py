@@ -18,6 +18,6 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices, found {len(devices)} — the dry-run must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import"
         )
-    from repro.distributed.sharding import make_mesh_compat
+    from repro.distributed.sharding import make_mesh
 
-    return make_mesh_compat(shape, axes, devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])

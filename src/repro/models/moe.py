@@ -15,11 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import (
-    batch_axes,
-    get_mesh,
-    shard_map_compat as _shard_map_compat,
-)
+from repro.distributed.sharding import batch_axes, get_mesh
 from .layers import _init
 
 
@@ -134,7 +130,7 @@ def moe_ffn(p, x, cfg):
             aux = jax.lax.pmean(aux, ba)
         return y, aux
 
-    fn = _shard_map_compat()(
+    fn = jax.shard_map(
         wrapped,
         mesh=mesh,
         in_specs=(

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import get_mesh, shard_map_compat as _shard_map_compat
+from repro.distributed.sharding import get_mesh
 
 
 def ef_init(params):
@@ -66,7 +66,7 @@ def compressed_psum_pod(x):
         return total.astype(jnp.float32) * scale
 
     rest = tuple(a for a in mesh.axis_names if a != "pod")
-    return _shard_map_compat()(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=P(*((rest[0] if rest else None,) + (None,) * (x.ndim - 1))),
         out_specs=P(*((rest[0] if rest else None,) + (None,) * (x.ndim - 1))),

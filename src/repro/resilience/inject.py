@@ -32,8 +32,8 @@ Pytest: ``tests/conftest.py`` exposes this as the ``chaos`` fixture
 (`chaos(seed=..., crash_at=...)` arms an injector for the test and
 disarms on teardown).  Benchmarks: ``--chaos SEED`` in
 benchmarks/resilience.py (and benchmarks/decode_parallel.py) drives the
-same helpers.  CI runs the fixed seed matrix in both jax legs
-(.github/workflows/ci.yml, chaos step).
+same helpers.  CI runs the fixed seed matrix (.github/workflows/ci.yml,
+chaos step).
 """
 from __future__ import annotations
 

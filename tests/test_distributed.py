@@ -82,8 +82,8 @@ _SUBPROC = textwrap.dedent("""
     from repro.launch.dryrun import parse_collectives, _lower_cell
     import dataclasses
 
-    from repro.distributed.sharding import make_mesh_compat
-    mesh = make_mesh_compat((2, 2, 4), ("pod", "data", "model"))
+    from repro.distributed.sharding import make_mesh
+    mesh = make_mesh((2, 2, 4), ("pod", "data", "model"))
     cfg = dataclasses.replace(
         get_config("{arch}").reduced(), fsdp=True,
         d_model=128, n_heads=8, head_dim=16, d_ff=256 if get_config("{arch}").d_ff else 0,
@@ -287,7 +287,7 @@ _FABRIC_SUBPROC = textwrap.dedent("""
     from repro.core.decode_engine import FrameReader, LZ4DecodeEngine
     from repro.core.frame import decode_frame_serial, frame_info
     from repro.core.lz4_types import MAX_BLOCK
-    from repro.distributed.sharding import make_mesh_compat
+    from repro.distributed.sharding import make_mesh
     from tests.test_distributed import _fabric_corpus
 
     assert len(jax.devices()) == 8
@@ -296,7 +296,7 @@ _FABRIC_SUBPROC = textwrap.dedent("""
                         ((2, 1), ("data", "model")),
                         ((2, 2), ("data", "model")),
                         ((1, 8), ("data", "model"))]:
-        mesh = make_mesh_compat(shape, axes)
+        mesh = make_mesh(shape, axes)
         S = shape[0] * shape[1]
         # 7 blocks: uneven against every multi-shard count here
         data = _fabric_corpus(7, seed=S)

@@ -75,8 +75,8 @@ def test_probe_linearity_subprocess():
         from repro.configs.base import get_config, ShapeConfig
         from repro.distributed.sharding import use_mesh
 
-        from repro.distributed.sharding import make_mesh_compat
-        mesh = make_mesh_compat((4, 4), ("data", "model"))
+        from repro.distributed.sharding import make_mesh
+        mesh = make_mesh((4, 4), ("data", "model"))
         cfg = dataclasses.replace(
             get_config("internlm2-1.8b"), d_model=256, n_heads=8, head_dim=32,
             n_kv_heads=4, d_ff=512, vocab_size=2048, fsdp=True)

@@ -88,3 +88,39 @@ def test_match_extend_end_of_block_cap():
     p = np.arange(n)
     expected = 4 + np.clip(n - 5 - (p + 4), 0, 32)
     np.testing.assert_array_equal(out, expected)
+
+
+# --- execution mode per backend (repro.kernels.backend) ----------------------
+
+from repro.kernels import backend  # noqa: E402
+
+
+@pytest.mark.parametrize("kernel", backend.KERNELS)
+def test_interpret_mode_per_backend(kernel):
+    """Interpreted off the TPU; compiled on a TPU, or a named refusal."""
+    assert backend.interpret_mode(kernel, "cpu") is True
+    if kernel in backend.TPU_REFUSED:
+        with pytest.raises(backend.PallasUnsupportedError, match=kernel) as e:
+            backend.interpret_mode(kernel, "tpu")
+        assert backend.TPU_REFUSED[kernel] in str(e.value)
+    else:
+        assert backend.interpret_mode(kernel, "tpu") is False
+
+
+def test_interpret_mode_rejects_unknown_kernel():
+    with pytest.raises(ValueError, match="unknown Pallas kernel"):
+        backend.interpret_mode("no_such_kernel", "tpu")
+
+
+def test_use_pallas_on_tpu_raises_for_a_refused_kernel(monkeypatch):
+    """The engine path resolves through the same place: with the backend
+    reported as TPU, tracing the Pallas match-extend raises instead of
+    interpreting."""
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "tpu")
+    n = 3 * 2048 + 7  # a shape no other test compiles, so this one traces
+    block = jnp.zeros((n + 40,), jnp.int32)
+    cand = jnp.zeros((n,), jnp.int32)
+    valid = jnp.zeros((n,), bool)
+    with pytest.raises(backend.PallasUnsupportedError, match="match_extend"):
+        ops.match_lengths(block, cand, valid, n, max_match=36,
+                          use_pallas=True)

@@ -25,8 +25,8 @@ _CODE = textwrap.dedent("""
     from repro.distributed.pipeline import pipeline_apply
     from repro.distributed.sharding import use_mesh
 
-    from repro.distributed.sharding import make_mesh_compat
-    mesh = make_mesh_compat((4,), ("pod",))
+    from repro.distributed.sharding import make_mesh
+    mesh = make_mesh((4,), ("pod",))
     rng = np.random.default_rng(0)
     S, d = 4, 16
     W = jnp.asarray(rng.normal(0, 0.5, (S, d, d)), jnp.float32)

@@ -450,7 +450,7 @@ _MESH_SUBPROC = textwrap.dedent("""
     import numpy as np
     from repro.core.engine import LZ4Engine
     from repro.core.decode_engine import LZ4DecodeEngine
-    from repro.distributed.sharding import make_mesh_compat
+    from repro.distributed.sharding import make_mesh
 
     assert len(jax.devices()) == 8
     rng = np.random.default_rng(7)
@@ -459,7 +459,7 @@ _MESH_SUBPROC = textwrap.dedent("""
     frame = LZ4Engine(micro_batch=4, shards=3).compress(data)
     results = {}
     for up in (False, True):
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         dec = LZ4DecodeEngine(mesh=mesh, executor="device",
                               plan_on_device=True, micro_batch=2,
                               use_pallas=up)

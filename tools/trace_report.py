@@ -16,8 +16,8 @@ traced wall clock.  This is the artifact BENCH entries and perf PRs embed:
 whether the write path is device-bound or drain-bound; `decode.plan` vs
 `decode.execute` vs `decode.verify` does the same for the read path.
 
-``--check`` schema-validates the bundle instead (CI runs this in both jax
-matrix legs): trace.json must be Chrome trace-event shaped, metrics.json
+``--check`` schema-validates the bundle instead (CI runs this):
+trace.json must be Chrome trace-event shaped, metrics.json
 must be a versioned registry snapshot.  Exit 0 iff valid.
 
 Usage:
