@@ -235,12 +235,14 @@ class DecodeStats:
 
     Lifecycle — ``engine.stats`` is REPLACED at the start of every
     `decode` / `decode_blocks` / `decode_to_device` call: it describes the
-    most recent call only (and `FrameReader` reads, which go through the
-    engine's `_decode_entries*` internals WITHOUT a reset, increment the
-    counters of whatever call came last).  For anything that must survive
+    most recent such call only.  `FrameReader` reads that reach the engine
+    (`read_block` on an LRU miss, `read_range` with missing blocks,
+    `read_range_device`) carry a per-read stats object of their own: each
+    counts as one call in ``engine.totals`` and the ``decode.*`` counters,
+    and none touches ``engine.stats``.  For anything that must survive
     across calls use ``engine.totals``, the cumulative sum merged in as
-    each public call finishes (even on error) — or the ``decode.*``
-    counters in `repro.obs.registry()` when telemetry is on.
+    each call finishes (even on error) — or the ``decode.*`` counters in
+    `repro.obs.registry()` when telemetry is on.
 
     ``host_bytes`` is the read-side twin of `EngineStats.host_bytes`: every
     CONTENT byte fetched device -> host by the "device" executor (exactly
@@ -251,6 +253,12 @@ class DecodeStats:
     the zero covers PLANNING too — the speculative planner parses the
     token stream in-graph, and only the per-row status vector (a few
     int32 scalars per block, metadata like the CRC sync) crosses back.
+
+    ``upload_bytes`` counts the other direction: block bytes handed host
+    -> device on the to-device paths by raw blocks (uploaded as stored)
+    and host-fallback blocks (uploaded after a host decode).  Compressed
+    payloads stacked into a decode dispatch are not counted.  A KV page
+    resume of one raw 64 KiB block per leaf reads 64 KiB here per leaf.
     """
 
     blocks: int = 0
@@ -262,6 +270,7 @@ class DecodeStats:
     device_blocks: int = 0     # blocks decoded inside the jit graph
     fallback_blocks: int = 0   # device executor blocks decoded on host
     host_bytes: int = 0        # bytes fetched device -> host
+    upload_bytes: int = 0      # raw/fallback block bytes put host -> device
     shards: int = 0            # sharded-fabric calls: mesh shard count
     calls: int = 0             # 1 per finished call (totals.calls sums them)
 
@@ -275,10 +284,16 @@ class DecodeStats:
         accumulation behind a lock (`_finish_call`); external accumulators
         shared across threads need their own.
         """
-        for f in ("blocks", "raw_blocks", "bytes_in", "bytes_out",
-                  "dispatches", "device_blocks", "fallback_blocks",
-                  "host_bytes"):
-            setattr(self, f, getattr(self, f) + getattr(other, f))
+        o = other  # plain adds: this runs once per page read
+        self.blocks += o.blocks
+        self.raw_blocks += o.raw_blocks
+        self.bytes_in += o.bytes_in
+        self.bytes_out += o.bytes_out
+        self.dispatches += o.dispatches
+        self.device_blocks += o.device_blocks
+        self.fallback_blocks += o.fallback_blocks
+        self.host_bytes += o.host_bytes
+        self.upload_bytes += o.upload_bytes
         self.parallel = self.parallel or other.parallel
         self.shards = max(self.shards, other.shards)
         self.calls += max(other.calls, 1)
@@ -416,6 +431,9 @@ class LZ4DecodeEngine:
                       "(plan overflowed DevicePlanCaps)").inc(s.fallback_blocks)
             r.counter("decode.host_bytes",
                       "content bytes fetched device -> host").inc(s.host_bytes)
+            r.counter("decode.upload_bytes",
+                      "raw/fallback block bytes put host -> device"
+                      ).inc(s.upload_bytes)
 
     # -- worker pool --------------------------------------------------------
 
@@ -782,12 +800,12 @@ class LZ4DecodeEngine:
             data = execute_plan(payload, plan).tobytes()
         with sp("decode.verify", block=i):
             check_block(i, b["usize"], b["crc"], data)
-        return self._host_result(data, to_device)
+        return self._host_result(data, to_device, i, st, sp)
 
     def _decode_entries_specplan(self, frame: bytes,
                                  entries: list[tuple[int, dict]],
-                                 to_device: bool = False, verify: bool = True,
-                                 st: DecodeStats | None = None):
+                                 to_device: bool, verify: bool,
+                                 st: DecodeStats):
         """`_decode_entries_device` with speculative in-graph planning.
 
         The whole per-block pipeline — header parse, chain select,
@@ -801,8 +819,6 @@ class LZ4DecodeEngine:
         ``table says`` message, caps overflows take the counted host
         fallback.
         """
-        if st is None:
-            st = self.stats
         from repro.kernels import ops as kops
 
         sp = obs.span_factory(self._obs_on())
@@ -815,7 +831,7 @@ class LZ4DecodeEngine:
             if b["raw"]:
                 with sp("decode.verify", block=i, raw=True):
                     check_block(i, b["usize"], b["crc"], payload)
-                out[j] = self._host_result(payload, to_device)
+                out[j] = self._host_result(payload, to_device, i, st, sp)
                 continue
             if len(payload) > self.caps.blk_cap:
                 out[j] = self._specplan_host_fallback(
@@ -854,24 +870,15 @@ class LZ4DecodeEngine:
 
         self._execute_specplan(jobs, finish, st,
                                compute_crc=bool(to_device and verify))
-        with sp("decode.verify", blocks=len(pending_crc), in_graph=True):
-            for i, got, want in pending_crc:
-                if int(got) != want:
-                    raise FrameFormatError(f"block {i}: checksum mismatch")
+        self._check_pending_crc(pending_crc, sp)
         return out
 
     # -- frames -------------------------------------------------------------
 
     def _decode_entries(self, frame: bytes, entries: list[tuple[int, dict]],
-                        st: DecodeStats | None = None) -> list[bytes]:
-        """Decode the given (index, table-entry) frame blocks, in order.
-
-        ``st`` is the owning call's stats object; `FrameReader` reads come
-        through without one and count into whatever call came last
-        (documented in `DecodeStats`).
-        """
-        if st is None:
-            st = self.stats
+                        st: DecodeStats) -> list[bytes]:
+        """Decode the given (index, table-entry) frame blocks, in order,
+        counting into ``st``, the owning call's stats object."""
         if self.mesh is not None and self.shards > 1:
             from repro.distributed import fabric
 
@@ -880,7 +887,9 @@ class LZ4DecodeEngine:
                       b["usize"], b["crc"], b["raw"]) for i, b in entries]
             return fabric.decode_items_sharded(self, items, st)
         if self.executor == "device":
-            return self._decode_entries_device(frame, entries, st=st)
+            return self._decode_entries_device(frame, entries,
+                                               to_device=False, verify=True,
+                                               st=st)
         ob = self._obs_on()
         sp = obs.span_factory(ob)
         out: list[bytes | None] = [None] * len(entries)
@@ -901,8 +910,8 @@ class LZ4DecodeEngine:
 
     def _decode_entries_device(self, frame: bytes,
                                entries: list[tuple[int, dict]],
-                               to_device: bool = False, verify: bool = True,
-                               st: DecodeStats | None = None):
+                               to_device: bool, verify: bool,
+                               st: DecodeStats):
         """Device-executor decode of (index, table-entry) frame blocks.
 
         ``to_device=True`` returns per-block DEVICE arrays (uint8) instead
@@ -912,10 +921,9 @@ class LZ4DecodeEngine:
         4-byte checksum is fetched for comparison against the table
         (raw/fallback blocks are uploaded host->device;
         `DecodeStats.host_bytes` stays the download-only *content* counter,
-        mirroring `EngineStats`, so verified device restores keep it at 0).
+        mirroring `EngineStats`, so verified device restores keep it at 0;
+        the uploads count in `DecodeStats.upload_bytes`).
         """
-        if st is None:
-            st = self.stats
         if self.plan_on_device:
             return self._decode_entries_specplan(
                 frame, entries, to_device=to_device, verify=verify, st=st)
@@ -932,7 +940,7 @@ class LZ4DecodeEngine:
             if b["raw"]:
                 with sp("decode.verify", block=i, raw=True):
                     check_block(i, b["usize"], b["crc"], payload)
-                out[j] = self._host_result(payload, to_device)
+                out[j] = self._host_result(payload, to_device, i, st, sp)
                 continue
             try:
                 plan, dplan = self._plan_for_device(payload, b["usize"])
@@ -955,7 +963,7 @@ class LZ4DecodeEngine:
                     data = execute_plan(payload, plan).tobytes()
                 with sp("decode.verify", block=i):
                     check_block(i, b["usize"], b["crc"], data)
-                out[j] = self._host_result(data, to_device)
+                out[j] = self._host_result(data, to_device, i, st, sp)
                 continue
             meta[j] = (i, b)
             jobs.append((j, payload, dplan))
@@ -981,19 +989,35 @@ class LZ4DecodeEngine:
             out[slot] = data
 
         self._execute_device(jobs, finish, st)
-        with sp("decode.verify", blocks=len(pending_crc), in_graph=True):
-            for i, got, want in pending_crc:
-                if int(got) != want:
-                    raise FrameFormatError(f"block {i}: checksum mismatch")
+        self._check_pending_crc(pending_crc, sp)
         return out
 
     @staticmethod
-    def _host_result(data: bytes, to_device: bool):
+    def _check_pending_crc(pending: list, sp) -> None:
+        """Compare the deferred in-graph CRCs with the table (the sync).
+
+        The span opens only when a CRC is pending: a read of raw blocks
+        alone has nothing to wait for and records no empty span."""
+        if not pending:
+            return
+        with sp("decode.verify", blocks=len(pending), in_graph=True):
+            for i, got, want in pending:
+                if int(got) != want:
+                    raise FrameFormatError(f"block {i}: checksum mismatch")
+
+    @staticmethod
+    def _host_result(data: bytes, to_device: bool, block: int,
+                     st: DecodeStats, sp):
+        """``data`` as the caller wants it: host bytes, or a device array
+        put host -> device (counted in ``st.upload_bytes``)."""
         if not to_device:
             return data
         import jax.numpy as jnp
 
-        return jnp.asarray(np.frombuffer(data, np.uint8))
+        with sp("decode.upload", block=block, bytes=len(data)):
+            arr = jnp.asarray(np.frombuffer(data, np.uint8))
+        st.upload_bytes += len(data)
+        return arr
 
     def salvage(self, frame: bytes):
         """Salvage pass over a (possibly damaged) frame: decode every
@@ -1190,6 +1214,33 @@ class FrameReader:
             while len(self._cache) > self._cache_blocks:
                 self._cache.popitem(last=False)
 
+    def _decode(self, idx, to_device: bool = False, verify: bool = True):
+        """Blocks ``idx`` through the engine as one read call: a stats
+        object of its own, finished into the engine's ``totals`` and the
+        ``decode.*`` counters; the engine's ``stats`` is left alone."""
+        blocks = self._blocks
+        entries = []
+        raw = cin = uout = 0
+        for i in idx:
+            b = blocks[i]
+            entries.append((i, b))
+            raw += b["raw"]
+            cin += b["csize"]
+            uout += b["usize"]
+        eng = self._engine
+        st = DecodeStats(blocks=len(entries), raw_blocks=raw, bytes_in=cin)
+        try:
+            if to_device:
+                parts = eng._decode_entries_device(
+                    self._frame, entries, to_device=True, verify=verify,
+                    st=st)
+            else:
+                parts = eng._decode_entries(self._frame, entries, st)
+            st.bytes_out = uout
+            return parts
+        finally:
+            eng._finish_call(st)
+
     def read_block(self, i: int) -> bytes:
         """Decode (or raw-slice) exactly block i, LRU-cached."""
         self.block_range(i)  # bounds check
@@ -1197,9 +1248,7 @@ class FrameReader:
             if i in self._cache:
                 self._cache.move_to_end(i)
                 return self._cache[i]
-        data = self._engine._decode_entries(
-            self._frame, [(i, self._blocks[i])]
-        )[0]
+        data = self._decode([i])[0]
         self._cache_put(i, data)
         return data
 
@@ -1221,8 +1270,7 @@ class FrameReader:
                     have[i] = self._cache[i]
         missing = [i for i in cover if i not in have]
         if missing:
-            for i, data in zip(missing, self._engine._decode_entries(
-                    self._frame, [(i, self._blocks[i]) for i in missing])):
+            for i, data in zip(missing, self._decode(missing)):
                 have[i] = data
                 self._cache_put(i, data)
         joined = have[cover[0]] if len(cover) == 1 else \
@@ -1245,12 +1293,12 @@ class FrameReader:
         cover = self.blocks_for_range(start, length)
         if len(cover) == 0:
             return jnp.zeros((0,), jnp.uint8)
-        parts = self._engine._decode_entries_device(
-            self._frame, [(i, self._blocks[i]) for i in cover],
-            to_device=True, verify=verify)
-        joined = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        parts = self._decode(cover, to_device=True, verify=verify)
         base = int(self._starts[cover[0]])
-        return joined[start - base: start - base + length]
+        with obs.span_factory(self._engine._obs_on())("decode.slice",
+                                                      bytes=length):
+            joined = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+            return joined[start - base: start - base + length]
 
     def read(self) -> bytes:
         """Full decode (parallel over all blocks)."""

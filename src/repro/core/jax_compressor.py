@@ -316,43 +316,52 @@ def compress_block_records(
     idx = jnp.arange(block.shape[0], dtype=jnp.int32)
     block = jnp.where(idx < n, block, 0)
 
+    # Each stage runs under a `jax.named_scope` ("lz4.<stage>"), so the
+    # per-operation events of a device trace name the stage they belong to;
+    # the scope names are stable (docs/observability.md lists them).
     p = jnp.arange(MAX_BLOCK, dtype=jnp.int32)
     if candidate_impl == "fused":
         # Single-pass datapath: hash, LVT candidate, word compare, and the
         # bounded extension come back from ONE kernel (or its jnp twin) —
         # no intermediate hash/word/candidate arrays round-trip through
         # the graph, and no sort anywhere.
-        cand, lengths = ops.fused_match_candidates(
-            block, n, positions=MAX_BLOCK, hash_bits=hash_bits, pws=pws,
-            max_match=max_match, use_pallas=use_pallas,
-        )
-        valid = lengths >= MIN_MATCH
+        with jax.named_scope("lz4.match"):
+            cand, lengths = ops.fused_match_candidates(
+                block, n, positions=MAX_BLOCK, hash_bits=hash_bits, pws=pws,
+                max_match=max_match, use_pallas=use_pallas,
+            )
+            valid = lengths >= MIN_MATCH
     else:
-        words, hashes = ops.hash_positions(block[: MAX_BLOCK + 3], hash_bits, use_pallas=use_pallas)
+        with jax.named_scope("lz4.hash"):
+            words, hashes = ops.hash_positions(block[: MAX_BLOCK + 3], hash_bits,
+                                               use_pallas=use_pallas)
         cand_fn = {
             "sort": _candidates,
             "sortkey": _candidates_sortkey,
             "scatter": _candidates_scatter,
         }[candidate_impl]
-        cand = cand_fn(hashes, n, hash_bits, pws)
+        with jax.named_scope("lz4.candidates"):
+            cand = cand_fn(hashes, n, hash_bits, pws)
 
-        has_cand = cand >= 0
-        wc = jnp.take(words, jnp.clip(cand, 0, MAX_BLOCK - 1))
-        valid4 = has_cand & (wc == words) & (p <= n - MF_LIMIT)
+        with jax.named_scope("lz4.extend"):
+            has_cand = cand >= 0
+            wc = jnp.take(words, jnp.clip(cand, 0, MAX_BLOCK - 1))
+            valid4 = has_cand & (wc == words) & (p <= n - MF_LIMIT)
+            lengths = ops.match_lengths(block, cand, valid4, n, max_match=max_match,
+                                        use_pallas=use_pallas)
+            valid = valid4 & (lengths >= MIN_MATCH)
 
-        lengths = ops.match_lengths(block, cand, valid4, n, max_match=max_match, use_pallas=use_pallas)
-        valid = valid4 & (lengths >= MIN_MATCH)
+    with jax.named_scope("lz4.select"):
+        if scan_impl == "sequential":
+            emit, pos, length = _select_sequential(valid, lengths, pws)
+        elif scan_impl == "associative":
+            emit, pos, length = _select_associative(valid, lengths, pws, max_match)
+        else:
+            raise ValueError(scan_impl)
 
-    if scan_impl == "sequential":
-        emit, pos, length = _select_sequential(valid, lengths, pws)
-    elif scan_impl == "associative":
-        emit, pos, length = _select_associative(valid, lengths, pws, max_match)
-    else:
-        raise ValueError(scan_impl)
-
-    offset = pos - jnp.take(cand, pos)
-    emit = emit & (length > 0)
-    size = _plan_size(emit, pos, length, n)
+        offset = pos - jnp.take(cand, pos)
+        emit = emit & (length > 0)
+        size = _plan_size(emit, pos, length, n)
     return BlockRecords(
         emit=emit,
         pos=jnp.where(emit, pos, -1),
@@ -399,13 +408,14 @@ def compress_block_bytes(
         use_pallas=use_pallas, scan_impl=scan_impl,
         candidate_impl=candidate_impl,
     )
-    block = block_u8.astype(jnp.int32)
-    idx = jnp.arange(block.shape[0], dtype=jnp.int32)
-    block = jnp.where(idx < n, block, 0)
-    out, total = ops.emit_bytes(
-        block, rec.emit, rec.pos, rec.length, rec.offset, n,
-        out_cap=out_cap, use_pallas=use_pallas,
-    )
+    with jax.named_scope("lz4.emit"):
+        block = block_u8.astype(jnp.int32)
+        idx = jnp.arange(block.shape[0], dtype=jnp.int32)
+        block = jnp.where(idx < n, block, 0)
+        out, total = ops.emit_bytes(
+            block, rec.emit, rec.pos, rec.length, rec.offset, n,
+            out_cap=out_cap, use_pallas=use_pallas,
+        )
     return out, total
 
 

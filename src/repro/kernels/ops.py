@@ -244,30 +244,31 @@ def decode_gather(blk_u8, lit_src, lit_dst, lit_len, match_dst, match_off,
     host oracles) and safe under vmap (a stacked micro-batch of plans
     decodes as one dispatch, exactly like the compress side).
     """
-    blk_i32 = blk_u8.astype(jnp.int32)
-    L = lit_src.shape[0]
-    M = match_dst.shape[0]
-    k = jnp.arange(out_cap, dtype=jnp.int32)
+    with jax.named_scope("lz4.gather"):
+        blk_i32 = blk_u8.astype(jnp.int32)
+        L = lit_src.shape[0]
+        M = match_dst.shape[0]
+        k = jnp.arange(out_cap, dtype=jnp.int32)
 
-    li = _span_map(lit_dst, n_lit, out_cap)
-    mi = _span_map(match_dst, n_match, out_cap)
-    liC = jnp.clip(li, 0, L - 1)
-    lit_end = jnp.take(lit_dst, liC) + jnp.take(lit_len, liC)
-    is_lit = (li >= 0) & (k < lit_end)
-    in_range = k < out_size
-    moff = jnp.take(match_off, jnp.clip(mi, 0, M - 1))
-    # Literal bytes (and everything past out_size) are fixed points of the
-    # source map; match bytes point back by their covering match's offset.
-    ptr = jnp.where(is_lit | ~in_range, k, k - moff)
-    ptr = jnp.clip(ptr, 0, out_cap - 1)
-    lit_blk = jnp.where(is_lit, jnp.take(lit_src, liC) + (k - jnp.take(lit_dst, liC)), 0)
+        li = _span_map(lit_dst, n_lit, out_cap)
+        mi = _span_map(match_dst, n_match, out_cap)
+        liC = jnp.clip(li, 0, L - 1)
+        lit_end = jnp.take(lit_dst, liC) + jnp.take(lit_len, liC)
+        is_lit = (li >= 0) & (k < lit_end)
+        in_range = k < out_size
+        moff = jnp.take(match_off, jnp.clip(mi, 0, M - 1))
+        # Literal bytes (and everything past out_size) are fixed points of the
+        # source map; match bytes point back by their covering match's offset.
+        ptr = jnp.where(is_lit | ~in_range, k, k - moff)
+        ptr = jnp.clip(ptr, 0, out_cap - 1)
+        lit_blk = jnp.where(is_lit, jnp.take(lit_src, liC) + (k - jnp.take(lit_dst, liC)), 0)
 
-    if use_pallas:
-        out = decode_wave_pallas(blk_i32, lit_blk, ptr,
-                                 jnp.asarray(out_size, jnp.int32)[None],
-                                 rounds=rounds)
-        return out.astype(jnp.uint8)
-    return ref.decode_gather_ref(blk_i32, lit_blk, ptr, out_size, rounds)
+        if use_pallas:
+            out = decode_wave_pallas(blk_i32, lit_blk, ptr,
+                                     jnp.asarray(out_size, jnp.int32)[None],
+                                     rounds=rounds)
+            return out.astype(jnp.uint8)
+        return ref.decode_gather_ref(blk_i32, lit_blk, ptr, out_size, rounds)
 
 
 # --- speculative in-graph planning -----------------------------------------
@@ -469,34 +470,35 @@ def crc32_bytes(data_u8, n):
     Used by the decode engine so `decode_to_device(verify=True)` can check
     integrity WITHOUT fetching the decoded payload to the host.
     """
-    K = data_u8.shape[0]
-    pad = (-K) % 8
-    d = data_u8.astype(jnp.uint32)
-    if pad:
-        d = jnp.concatenate([d, jnp.zeros((pad,), jnp.uint32)])
-    chunks = d.reshape(-1, 8)
-    T = jnp.asarray(_crc_slice8_tables())
-    n = jnp.asarray(n, jnp.int32)
+    with jax.named_scope("lz4.crc"):
+        K = data_u8.shape[0]
+        pad = (-K) % 8
+        d = data_u8.astype(jnp.uint32)
+        if pad:
+            d = jnp.concatenate([d, jnp.zeros((pad,), jnp.uint32)])
+        chunks = d.reshape(-1, 8)
+        T = jnp.asarray(_crc_slice8_tables())
+        n = jnp.asarray(n, jnp.int32)
 
-    def step(crc, xs):
-        chunk, s = xs
-        base = s * 8
-        # Full chunk: fold 4 bytes into the running crc, then one table
-        # lookup per byte of the 8-byte slice.
-        x = crc ^ (chunk[0] | (chunk[1] << 8) | (chunk[2] << 16)
-                   | (chunk[3] << 24))
-        full = (T[7, x & 0xFF] ^ T[6, (x >> 8) & 0xFF]
-                ^ T[5, (x >> 16) & 0xFF] ^ T[4, (x >> 24) & 0xFF]
-                ^ T[3, chunk[4]] ^ T[2, chunk[5]]
-                ^ T[1, chunk[6]] ^ T[0, chunk[7]])
-        # Ragged tail: the same 8 bytes one at a time, each masked by n.
-        c = crc
-        for j in range(8):
-            upd = T[0, (c ^ chunk[j]) & 0xFF] ^ (c >> 8)
-            c = jnp.where(base + j < n, upd, c)
-        return jnp.where(base + 8 <= n, full, c), None
+        def step(crc, xs):
+            chunk, s = xs
+            base = s * 8
+            # Full chunk: fold 4 bytes into the running crc, then one table
+            # lookup per byte of the 8-byte slice.
+            x = crc ^ (chunk[0] | (chunk[1] << 8) | (chunk[2] << 16)
+                       | (chunk[3] << 24))
+            full = (T[7, x & 0xFF] ^ T[6, (x >> 8) & 0xFF]
+                    ^ T[5, (x >> 16) & 0xFF] ^ T[4, (x >> 24) & 0xFF]
+                    ^ T[3, chunk[4]] ^ T[2, chunk[5]]
+                    ^ T[1, chunk[6]] ^ T[0, chunk[7]])
+            # Ragged tail: the same 8 bytes one at a time, each masked by n.
+            c = crc
+            for j in range(8):
+                upd = T[0, (c ^ chunk[j]) & 0xFF] ^ (c >> 8)
+                c = jnp.where(base + j < n, upd, c)
+            return jnp.where(base + 8 <= n, full, c), None
 
-    steps = jnp.arange(chunks.shape[0], dtype=jnp.int32)
-    crc0 = jnp.uint32(0xFFFFFFFF)
-    crc, _ = jax.lax.scan(step, crc0, (chunks, steps))
-    return crc ^ jnp.uint32(0xFFFFFFFF)
+        steps = jnp.arange(chunks.shape[0], dtype=jnp.int32)
+        crc0 = jnp.uint32(0xFFFFFFFF)
+        crc, _ = jax.lax.scan(step, crc0, (chunks, steps))
+        return crc ^ jnp.uint32(0xFFFFFFFF)

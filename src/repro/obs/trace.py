@@ -19,18 +19,29 @@ Exports:
     events, microsecond timestamps) that chrome://tracing and Perfetto
     (https://ui.perfetto.dev) load directly;
   * `Tracer.jsonl_events()` — one JSON object per finished span (name,
-    thread, start_ns, dur_ns, depth, parent, args), the grep-able log.
+    thread, start_ns, dur_ns, depth, parent, call, args), the grep-able
+    log.
+
+Every span carries ``call``: a per-tracer sequence number taken by the
+outermost span open on its thread and inherited by the spans nested in
+it on that thread, so the stages of one request that run on its thread
+(one `serving.read_leaf` and its reader stages) group by one number in
+both exports and in the profiler.  Spans opened in pool workers (the
+thread and process executors' `decode.plan` / `decode.execute`) start
+a call of their own.
 
 Optional bridge: `configure(jax_annotations=True)` (or env
 ``REPRO_OBS_JAX=1``) wraps every span in `jax.profiler.TraceAnnotation`,
 so the same span names show up inside XLA device traces on real hardware
-and host spans can be lined up against device timelines.  Lazy import —
-the tracer itself never requires jax.
+and host spans can be lined up against device timelines.  The event keeps
+the bare span name; ``call`` and the span's scalar args at entry ride as
+the event's stats.  Lazy import — the tracer itself never requires jax.
 
 See docs/observability.md for the span catalog and Perfetto how-to.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -60,7 +71,7 @@ NOOP_SPAN = _NoopSpan()
 class Span:
     """One live timed section.  Use via `Tracer.span` / `repro.obs.span`."""
 
-    __slots__ = ("tracer", "name", "args", "depth", "parent",
+    __slots__ = ("tracer", "name", "args", "depth", "parent", "call",
                  "start_ns", "_jax_ctx")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict | None):
@@ -69,6 +80,7 @@ class Span:
         self.args = args
         self.depth = 0
         self.parent: str | None = None
+        self.call = 0
         self.start_ns = 0
         self._jax_ctx = None
 
@@ -86,10 +98,16 @@ class Span:
             top = stack[-1]
             self.depth = top.depth + 1
             self.parent = top.name
+            self.call = top.call
+        else:
+            self.call = next(self.tracer._calls)
         stack.append(self)
         ann = self.tracer._annotation_cls()
         if ann is not None:
-            self._jax_ctx = ann(self.name)
+            stats = {k: v for k, v in (self.args or {}).items()
+                     if isinstance(v, (str, int, float, bool))}
+            stats["call"] = self.call
+            self._jax_ctx = ann(self.name, **stats)
             self._jax_ctx.__enter__()
         self.start_ns = time.perf_counter_ns()
         return self
@@ -125,6 +143,7 @@ class Tracer:
         self._jax_annotations = False
         self._ann_cls = None
         self._n_events = 0
+        self._calls = itertools.count(1)  # next() is atomic under the GIL
 
     # -- configuration ------------------------------------------------------
 
@@ -173,7 +192,7 @@ class Tracer:
         self._n_events += 1  # benign race: the cap is a bound, not a ledger
         self._events().append(
             (span.name, span.start_ns, end_ns - span.start_ns,
-             span.depth, span.parent, span.args)
+             span.depth, span.parent, span.call, span.args)
         )
 
     # -- export -------------------------------------------------------------
@@ -184,11 +203,11 @@ class Tracer:
             bufs = [(tid, name, list(ev)) for tid, name, ev in self._buffers]
         rows = []
         for tid, tname, events in bufs:
-            for name, start, dur, depth, parent, args in events:
+            for name, start, dur, depth, parent, call, args in events:
                 rows.append({
                     "name": name, "tid": tid, "thread": tname,
                     "start_ns": start - self.origin_ns, "dur_ns": dur,
-                    "depth": depth, "parent": parent,
+                    "depth": depth, "parent": parent, "call": call,
                     "args": args or {},
                 })
         rows.sort(key=lambda r: r["start_ns"])
@@ -208,16 +227,16 @@ class Tracer:
                 "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
                 "args": {"name": tname},
             })
-            for name, start, dur, depth, parent, args in buf:
-                ev = {
+            for name, start, dur, depth, parent, call, args in buf:
+                events.append({
                     "name": name, "cat": "repro", "ph": "X", "pid": pid,
                     "tid": tid,
                     "ts": (start - self.origin_ns) / 1e3,   # microseconds
                     "dur": dur / 1e3,
-                }
-                if args:
-                    ev["args"] = {k: _jsonable(v) for k, v in args.items()}
-                events.append(ev)
+                    "args": {**{k: _jsonable(v)
+                                for k, v in (args or {}).items()},
+                             "call": call},
+                })
         return {
             "traceEvents": events,
             "displayTimeUnit": "ms",

@@ -139,9 +139,10 @@ def _device_view(u8, dtype: np.dtype, shape):
     device-side twin of ``np.frombuffer(...).reshape(...)`` (bitcast, no
     transfer; byte order is the host's little-endian layout either way)."""
     dt = np.dtype(dtype)
-    if dt.itemsize > 1:
-        u8 = u8.reshape(-1, dt.itemsize)
-    return jax.lax.bitcast_convert_type(u8, dt).reshape(shape)
+    with obs.span("serving.view", dtype=dt.name):
+        if dt.itemsize > 1:
+            u8 = u8.reshape(-1, dt.itemsize)
+        return jax.lax.bitcast_convert_type(u8, dt).reshape(shape)
 
 
 def restore_cache(obj, decode_engine=None, to_device: bool = False,
@@ -294,7 +295,8 @@ class OffloadedCacheReader:
             count = total - start
         if start < 0 or count < 0 or start + count > total:
             raise ValueError(f"slice [{start}, {start + count}) outside leaf of {total}")
-        t0 = time.perf_counter()
+        timed = obs.is_enabled()
+        t0 = time.perf_counter() if timed else 0.0
         with obs.span("serving.read_leaf", leaf=i, count=count,
                       to_device=self._to_device):
             if self._to_device:
@@ -306,7 +308,7 @@ class OffloadedCacheReader:
                 raw = self.read_leaf_bytes(i, start * dtype.itemsize,
                                            count * dtype.itemsize)
                 out = np.frombuffer(raw, dtype)
-        if obs.is_enabled():
+        if timed:
             obs.histogram("serving.read_leaf_seconds",
                           help="partial-restore (resume) read latency"
                           ).observe(time.perf_counter() - t0)

@@ -13,7 +13,11 @@ Covers the `repro.obs` contract end to end:
   * the disabled path: no events recorded, frames byte-identical with
     telemetry on vs off, and a <2% overhead guard on a compress microloop;
   * EngineStats/DecodeStats lifecycle: per-call `stats` vs lifetime
-    `totals`, `as_dict()` round-trips;
+    `totals`, `as_dict()` round-trips; `FrameReader` reads as calls of
+    their own, with the host -> device upload counter;
+  * the profiler bridge: span names, args and the ``call`` id as
+    `TraceAnnotation` stats, read back from an `.xplane.pb`; the device
+    stages' `jax.named_scope` names in the compiled graphs' metadata;
   * `tools/trace_report.py` round-trip over a real exported bundle.
 """
 from __future__ import annotations
@@ -141,6 +145,36 @@ def test_tracer_reset():
     with tr.span("y"):  # usable after reset
         pass
     assert len(tr.finished()) == 1
+
+
+def test_call_id_groups_nested_spans_per_thread():
+    tr = Tracer()
+    with tr.span("req", leaf=0):
+        with tr.span("stage.a"):
+            with tr.span("stage.b"):
+                pass
+    with tr.span("req", leaf=1):
+        pass
+
+    def other():
+        with tr.span("req.other"):
+            with tr.span("stage.c"):
+                pass
+
+    with ThreadPoolExecutor(max_workers=1) as ex:
+        ex.submit(other).result()
+    rows = tr.finished()
+    by = {(r["name"], r["args"].get("leaf")): r["call"] for r in rows}
+    first = by[("req", 0)]
+    assert by[("stage.a", None)] == by[("stage.b", None)] == first
+    assert by[("req", 1)] != first
+    assert by[("req.other", None)] == by[("stage.c", None)]
+    assert len({r["call"] for r in rows}) == 3
+    # Both exports carry it.
+    xs = [e for e in tr.chrome_trace()["traceEvents"] if e["ph"] == "X"]
+    assert {e["args"]["call"] for e in xs} == {r["call"] for r in rows}
+    lines = [json.loads(ln) for ln in tr.jsonl_events().splitlines()]
+    assert [ln["call"] for ln in lines] == [r["call"] for r in rows]
 
 
 # ---------------------------------------------------------------------------
@@ -282,6 +316,39 @@ def test_span_factory_gating(enabled_obs):
     assert names == {"real"}
 
 
+def test_jax_bridge_carries_args_and_call(tmp_path):
+    """With the bridge on, a span is a `TraceAnnotation` whose event keeps
+    the bare span name (what `bench/devtrace.py` matches) and carries the
+    span's scalar args and its ``call`` as stats."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    tr.set_jax_annotations(True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("serving.read_leaf", leaf=3, to_device=True):
+            with tr.span("decode.upload", block=7, bytes=65536):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("serving.read_leaf", "decode.upload"):
+                    events[e.name] = dict(e.stats)
+    assert set(events) == {"serving.read_leaf", "decode.upload"}
+    outer, inner = events["serving.read_leaf"], events["decode.upload"]
+    assert outer["leaf"] == 3 and outer["to_device"] in (True, 1)
+    assert inner["block"] == 7 and inner["bytes"] == 65536
+    (call,) = {r["call"] for r in tr.finished()}
+    assert outer["call"] == inner["call"] == call
+
+
 # ---------------------------------------------------------------------------
 # engine integration: spans, stats lifecycle, identical output, overhead
 # ---------------------------------------------------------------------------
@@ -336,6 +403,72 @@ def test_stats_per_call_vs_totals():
     assert d["calls"] == 2 and d["bytes_in"] == 2 * per_call
     dd = dec.totals.as_dict()
     assert dd["calls"] == 2 and isinstance(dd, dict)
+
+
+def test_frame_reader_reads_count_as_calls(enabled_obs):
+    """Every `FrameReader` read that reaches the engine is one decode call:
+    it lands in ``totals`` and the ``decode.*`` counters, and leaves the
+    previous call's ``stats`` alone.  A raw block read onto the device is
+    uploaded whole."""
+    from repro.core import FrameReader, LZ4DecodeEngine, LZ4Engine
+    from repro.core.lz4_types import MAX_BLOCK
+
+    text = _data(1)
+    noise = np.random.default_rng(5).integers(0, 256, MAX_BLOCK, np.uint8).tobytes()
+    frame = LZ4Engine(micro_batch=2).compress(text + noise)
+    dec = LZ4DecodeEngine(executor="device", telemetry=True)
+    assert dec.decode(frame) == text + noise
+    last = dec.stats.as_dict()
+    calls = dec.totals.calls
+    reader = FrameReader(frame, engine=dec)
+    assert reader.read_block(0) == text
+    assert reader.read_range(MAX_BLOCK - 4, 8) == (text + noise)[MAX_BLOCK - 4: MAX_BLOCK + 4]
+    page = reader.read_range_device(MAX_BLOCK + 100, 4096)
+    assert np.asarray(page).tobytes() == noise[100: 4196]
+    assert dec.stats.as_dict() == last, "reads must not touch the last call's stats"
+    assert dec.totals.calls == calls + 3
+    assert dec.totals.upload_bytes == MAX_BLOCK  # the raw block, uploaded whole
+    counters = obs.snapshot()["metrics"]["counters"]
+    assert counters["decode.calls"] == calls + 3
+    assert counters["decode.upload_bytes"] == MAX_BLOCK
+    assert counters["decode.host_bytes"] == dec.totals.host_bytes
+    names = [e["name"] for e in obs.tracer().finished()]
+    assert names.count("decode.upload") == 1 and names.count("decode.slice") == 1
+
+
+def test_device_stage_scopes_in_compiled_graphs():
+    """The device stages keep `jax.named_scope` names in the compiled HLO's
+    ``op_name`` metadata, under the program names a trace shows."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.decode_engine import _device_decode_compiled
+    from repro.core.decode_plan import DevicePlanCaps
+    from repro.core.jax_compressor import _PAD, compress_block_bytes
+    from repro.core.lz4_types import MAX_BLOCK
+    from repro.kernels.ops import crc32_bytes
+
+    def hlo(fn, *shapes):
+        return fn.lower(*(jax.ShapeDtypeStruct(s, d) for s, d in shapes)).compile().as_text()
+
+    blocks = ((2, MAX_BLOCK + _PAD), jnp.uint8), ((2,), jnp.int32)
+    staged = hlo(jax.jit(jax.vmap(compress_block_bytes)), *blocks)
+    fused = hlo(jax.jit(jax.vmap(functools.partial(compress_block_bytes,
+                                                   candidate_impl="fused"))), *blocks)
+    assert "jit(compress_block_bytes)" in staged
+    for scope in ("lz4.hash", "lz4.candidates", "lz4.extend", "lz4.select", "lz4.emit"):
+        assert f"/{scope}/" in staged, scope
+    assert "/lz4.match/" in fused and "/lz4.select/" in fused
+    caps = DevicePlanCaps()
+    i32 = jnp.int32
+    gather = hlo(_device_decode_compiled(caps.out_cap, 4, False),
+                 ((2, caps.blk_cap), jnp.uint8), *[((2, caps.max_lit), i32)] * 3,
+                 *[((2, caps.max_match), i32)] * 2, *[((2,), i32)] * 3)
+    assert "jit(decode_gather)" in gather and "/lz4.gather/" in gather
+    crc = hlo(crc32_bytes, ((4096,), jnp.uint8), ((), i32))
+    assert "jit(crc32_bytes)" in crc and "/lz4.crc/" in crc
 
 
 def test_frames_identical_telemetry_on_off(enabled_obs):

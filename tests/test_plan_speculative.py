@@ -376,10 +376,13 @@ def test_specplan_read_range_device_zero_host_bytes(engine, spec_engine):
     data = _frame_corpus()["multi_text"]
     frame = engine.compress(data)
     reader = FrameReader(frame, engine=spec_engine)
+    calls, host = spec_engine.totals.calls, spec_engine.totals.host_bytes
     for start, length in [(0, 1), (MAX_BLOCK - 3, 7), (70000, 9000)]:
         got = np.asarray(reader.read_range_device(start, length)).tobytes()
         assert got == data[start: start + length], (start, length)
-    assert spec_engine.stats.host_bytes == 0
+    # Each read is a call of its own in `totals`: three, fetching nothing.
+    assert spec_engine.totals.calls == calls + 3
+    assert spec_engine.totals.host_bytes == host
 
 
 def test_specplan_offloaded_reader_to_device():
