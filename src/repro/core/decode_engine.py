@@ -917,7 +917,7 @@ class LZ4DecodeEngine:
         ``to_device=True`` returns per-block DEVICE arrays (uint8) instead
         of host bytes — and the content NEVER crosses the device->host
         boundary: with ``verify=True`` each block's CRC32 is computed
-        in-graph (slice-by-8, `kernels.ops.crc32_bytes`) and only the
+        in-graph (GF(2) matmuls, `kernels.ops.crc32_bytes`) and only the
         4-byte checksum is fetched for comparison against the table
         (raw/fallback blocks are uploaded host->device;
         `DecodeStats.host_bytes` stays the download-only *content* counter,
@@ -1078,7 +1078,7 @@ class LZ4DecodeEngine:
         uploaded, decoded in-graph, and concatenated on device, so a
         KV-offload restore never materializes the plaintext on the host.
         ``verify=True`` (default) checks each block's CRC32 *on device*
-        (slice-by-8 table walk in-graph, `kernels.ops.crc32_bytes`) and
+        (GF(2) matmuls in-graph, `kernels.ops.crc32_bytes`) and
         fetches only the 4-byte checksum for comparison — verified
         restores keep `host_bytes` at 0 too; ``verify=False`` skips even
         that scalar sync (the frame table's structural validation and the

@@ -9,8 +9,8 @@ emit_scatter.py (device-side byte emission — the write path's last stage,
 so compressed bytes never round-trip through host NumPy), decode_wave.py
 (device-side plan execution — pointer-doubling source resolve + byte
 gather, the read path's mirror of emit_scatter).  ops.py additionally
-carries `crc32_bytes`, the in-graph slice-by-8 CRC-32 that keeps verified
-device restores free of content fetches.
+carries `crc32_bytes`, the in-graph CRC-32 (GF(2) matmuls over bit
+chunks) that keeps verified device restores free of content fetches.
 
 Layout per kernel: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 dispatch wrappers), ref.py (pure-jnp oracles).  backend.py resolves each

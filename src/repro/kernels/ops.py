@@ -13,6 +13,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.lz4_types import MIN_MATCH
 
@@ -435,70 +436,92 @@ def plan_decode(blk_u8, n, max_out, out_cap: int, max_lit: int,
     return out, status, crc
 
 
-@functools.lru_cache(maxsize=1)
-def _crc_slice8_tables():
-    """The 8 x 256 slice-by-8 lookup tables for CRC-32 (IEEE, reflected —
-    zlib/binascii-compatible).  Built once on host; embedded in the graph
-    as a constant so the checksum runs device-side."""
-    import numpy as np
+_CRC_CHUNK = 1024       # bytes per row of the chunk matmul
+_CRC_PIECE = 1 << 20    # bytes per loop step; bounds the bit unpack's memory
 
-    poly = 0xEDB88320
-    t = np.zeros((8, 256), np.uint32)
-    for i in range(256):
-        c = i
-        for _ in range(8):
-            c = (c >> 1) ^ (poly if c & 1 else 0)
-        t[0, i] = c
-    for k in range(1, 8):
-        prev = t[k - 1]
-        t[k] = (prev >> 8) ^ t[0, prev & 0xFF]
-    return t
+
+def _gf2_pow(m, e):
+    out = np.eye(32, dtype=np.int64)
+    for bit in bin(e)[:1:-1]:
+        out, m = (out @ m & 1 if bit == "1" else out), m @ m & 1
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _crc_constants(chunks: int, padded: int):
+    """CRC-32 (IEEE, reflected: zlib/binascii) as GF(2) matrices, built once
+    on the host and embedded in `crc32_bytes`' graph.  A register is a
+    32-bit row vector, a map M takes v to v @ M mod 2, A is one zero byte.
+
+    G (8 * CHUNK, 32): row (bit k, byte i) is the zero-init register after
+    a chunk holding only that bit.  H (32 * chunks, 32): block c is
+    A^(CHUNK * (chunks-1-c)), carrying chunk c's register to the piece's
+    end.  Then A^piece, and A^-(2^i) for each bit of `padded`."""
+    t = np.arange(256, dtype=np.uint32)
+    for _ in range(8):
+        t = np.where(t & 1, (t >> 1) ^ np.uint32(0xEDB88320), t >> 1)
+    unit = np.uint32(1) << np.arange(32, dtype=np.uint32)
+    bits = lambda r: (r[..., None] >> np.arange(32, dtype=np.uint32) & 1
+                      ).astype(np.int64)
+    rows = [t[unit[:8]]]
+    for _ in range(_CRC_CHUNK - 1):
+        rows.append(t[rows[-1] & 0xFF] ^ (rows[-1] >> 8))
+    A = bits(t[unit & 0xFF] ^ (unit >> 8))
+    i = np.argsort(t >> 24).astype(np.uint32)[unit >> 24]  # top bytes unique
+    inv = [bits(((unit ^ t[i]) << 8) | i)]
+    for _ in range(padded.bit_length() - 1):
+        inv.append(inv[-1] @ inv[-1] & 1)
+    H = [_gf2_pow(A, _CRC_CHUNK * c) for c in range(chunks - 1, -1, -1)]
+    return (bits(np.stack(rows[::-1], axis=1)).reshape(-1, 32),
+            np.concatenate(H), _gf2_pow(A, _CRC_CHUNK * chunks), np.stack(inv))
+
+
+def _gf2_dot(a, m):
+    """``a @ m`` over GF(2): 0/1 operands in bf16, exact integer sums in
+    f32, then mod 2 (int32 bits)."""
+    s = jnp.dot(a.astype(jnp.bfloat16), jnp.asarray(m, jnp.bfloat16),
+                preferred_element_type=jnp.float32)
+    return s.astype(jnp.int32) & 1
 
 
 @jax.jit
 def crc32_bytes(data_u8, n):
-    """CRC-32 of ``data_u8[:n]``, entirely in-graph (slice-by-8).
+    """CRC-32 of ``data_u8[:n]``, entirely in-graph (GF(2) matmuls).
 
     data_u8 : (K,) uint8 buffer (content past `n` is ignored)
     n       : scalar int32 byte count, 0 <= n <= K
 
     Returns a () uint32 equal to ``binascii.crc32(bytes(data_u8[:n]))`` —
-    the frame's `block_crc`.  Each scan step folds 8 bytes through the
-    precomputed tables (the standard slice-by-8 formulation); a masked
-    byte-serial variant of the same step handles the ragged tail, so `n`
-    stays a traced value and one compiled graph covers every block size.
-    Used by the decode engine so `decode_to_device(verify=True)` can check
-    integrity WITHOUT fetching the decoded payload to the host.
+    the frame's `block_crc`.  CRC-32 is linear over GF(2): the bytes past
+    `n` are zeroed, one bits x G matmul gives every 1 KiB chunk's register
+    and one x H matmul carries them to the piece's end; pieces of 1 MiB
+    fold in a loop.  The zero tail is undone by log2(K) steps of A^-(2^i),
+    chosen by the bits of K - n, so `n` stays traced and one compiled graph
+    covers every block size.  Used by the decode engine so
+    `decode_to_device(verify=True)` checks integrity WITHOUT fetching the
+    decoded payload to the host.
     """
     with jax.named_scope("lz4.crc"):
         K = data_u8.shape[0]
-        pad = (-K) % 8
-        d = data_u8.astype(jnp.uint32)
-        if pad:
-            d = jnp.concatenate([d, jnp.zeros((pad,), jnp.uint32)])
-        chunks = d.reshape(-1, 8)
-        T = jnp.asarray(_crc_slice8_tables())
+        piece = min(_CRC_PIECE, -(-K // _CRC_CHUNK) * _CRC_CHUNK)
+        chunks = piece // _CRC_CHUNK
+        x = _pad_to(data_u8, piece).reshape(-1, piece)
+        G, H, Ap, inv = _crc_constants(chunks, x.size)
         n = jnp.asarray(n, jnp.int32)
 
-        def step(crc, xs):
-            chunk, s = xs
-            base = s * 8
-            # Full chunk: fold 4 bytes into the running crc, then one table
-            # lookup per byte of the 8-byte slice.
-            x = crc ^ (chunk[0] | (chunk[1] << 8) | (chunk[2] << 16)
-                       | (chunk[3] << 24))
-            full = (T[7, x & 0xFF] ^ T[6, (x >> 8) & 0xFF]
-                    ^ T[5, (x >> 16) & 0xFF] ^ T[4, (x >> 24) & 0xFF]
-                    ^ T[3, chunk[4]] ^ T[2, chunk[5]]
-                    ^ T[1, chunk[6]] ^ T[0, chunk[7]])
-            # Ragged tail: the same 8 bytes one at a time, each masked by n.
-            c = crc
-            for j in range(8):
-                upd = T[0, (c ^ chunk[j]) & 0xFF] ^ (c >> 8)
-                c = jnp.where(base + j < n, upd, c)
-            return jnp.where(base + 8 <= n, full, c), None
+        def fold(v, xs):
+            piece_u8, start = xs
+            b = jnp.where(jnp.arange(piece) < n - start, piece_u8, 0)
+            b = b.reshape(chunks, 1, _CRC_CHUNK) >> jnp.arange(
+                8, dtype=jnp.uint8)[:, None] & 1
+            regs = _gf2_dot(b.reshape(chunks, 8 * _CRC_CHUNK), G)
+            return _gf2_dot(v, Ap) ^ _gf2_dot(regs.reshape(-1), H), None
 
-        steps = jnp.arange(chunks.shape[0], dtype=jnp.int32)
-        crc0 = jnp.uint32(0xFFFFFFFF)
-        crc, _ = jax.lax.scan(step, crc0, (chunks, steps))
-        return crc ^ jnp.uint32(0xFFFFFFFF)
+        starts = jnp.arange(x.shape[0], dtype=jnp.int32) * piece
+        # The carry starts as the 0xFFFFFFFF initial register.
+        v, _ = jax.lax.scan(fold, jnp.ones(32, jnp.int32), (x, starts))
+        pad = x.size - n
+        for i in range(inv.shape[0]):
+            v = jnp.where(pad >> i & 1, _gf2_dot(v, inv[i]), v)
+        return ~jnp.sum(v.astype(jnp.uint32)
+                        << jnp.arange(32, dtype=jnp.uint32))

@@ -163,10 +163,10 @@ def restore_cache(obj, decode_engine=None, to_device: bool = False,
     the jit graph and the restored leaves are assembled as device arrays.
     The restore is fully accelerator-to-accelerator either way — with the
     default ``verify=True`` each block's CRC32 is computed in-graph
-    (`kernels.ops.crc32_bytes`) and only the 4-byte checksum is synced for
-    comparison, so zero plaintext bytes cross to the host
-    (`DecodeStats.host_bytes` 0); ``verify=False`` skips even that scalar
-    sync and defers integrity to the caller.  An engine configured with
+    (`kernels.ops.crc32_bytes`, GF(2) matmuls) and only the 4-byte
+    checksum is synced for comparison, so zero plaintext bytes cross to
+    the host (`DecodeStats.host_bytes` 0); ``verify=False`` skips even
+    that scalar sync and defers integrity to the caller.  An engine configured with
     ``plan_on_device=True`` keeps even token-stream PLANNING on device
     (the speculative planner, kernels/plan_speculative.py) — the restore
     then has no per-byte host stage at all.

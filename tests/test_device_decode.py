@@ -368,7 +368,7 @@ def test_decode_to_device_matches_and_transfers_nothing(engine, device_engine):
     dev = device_engine.decode_to_device(frame)
     assert isinstance(dev, jax.Array)
     assert np.asarray(dev).tobytes() == data
-    # verify=True checks CRCs IN-GRAPH (slice-by-8, ops.crc32_bytes): the
+    # verify=True checks CRCs IN-GRAPH (GF(2) matmuls, ops.crc32_bytes): the
     # decoded content itself never crosses to the host even when verified.
     assert device_engine.stats.host_bytes == 0
     # verify=False: additionally skips the per-block checksum sync.

@@ -18,8 +18,9 @@
     GPU/TPU-without-Pallas, fused on TPU with use_pallas — the expected
     accelerator shapes), rejects unknown names, and the RESOLVED choice
     lands in `EngineStats.candidate_impl`;
-  * `kernels.ops.crc32_bytes` (in-graph slice-by-8 CRC-32, the device-side
-    verify satellite) equals `binascii.crc32` across length corners.
+  * `kernels.ops.crc32_bytes` (in-graph CRC-32 as GF(2) matmuls, the
+    device-side verify) equals `binascii.crc32` across buffer sizes, chunk
+    and piece boundaries, under `vmap`, with one compiled graph per size.
 """
 import binascii
 
@@ -289,25 +290,70 @@ def test_resolve_candidate_impl():
 # In-graph CRC-32 (the device-verify satellite)
 # ---------------------------------------------------------------------------
 
-def test_crc32_bytes_matches_binascii():
+def _crc(buf, n):
+    import jax.numpy as jnp
+
+    return int(ops.crc32_bytes(jnp.asarray(buf), jnp.int32(n)))
+
+
+def _crc_lengths(K, rng):
+    """n at 0, 1, the old corner list, every chunk and piece boundary +-1
+    (above 64 KiB: the first and last four chunk boundaries, those around
+    each piece boundary and 16 drawn at random), K - 1 and K."""
+    chunk, piece = ops._CRC_CHUNK, ops._CRC_PIECE
+    edges = list(range(chunk, K + 1, chunk))
+    if len(edges) > 64:
+        drawn = rng.choice(edges, 16, replace=False).tolist()
+        around = [p + d for p in range(piece, K + 1, piece)
+                  for d in (-chunk, 0, chunk)]
+        edges = edges[:4] + edges[-4:] + drawn + around
+    ns = {0, 1, 3, 7, 8, 9, 15, 16, 70, 255, 256, 257, 1000, K - 1, K}
+    ns |= {e + d for e in edges for d in (-1, 0, 1)}
+    return sorted(n for n in ns if 0 <= n <= K)
+
+
+@pytest.mark.parametrize("K", [1, 7, 8, 9, 1000, 4096, MAX_BLOCK, 1 << 21])
+def test_crc32_bytes_matches_binascii(K):
+    rng = _rng()
+    buf = rng.integers(0, 256, K, np.uint8)
+    lengths = _crc_lengths(K, rng)
+    for n in lengths:
+        assert _crc(buf, n) == binascii.crc32(buf[:n].tobytes()), n
+    # All-0x00 (e.g. 70 zero bytes of a 64 KB row) and all-0xFF data.
+    for fill in (0x00, 0xFF):
+        const = np.full(K, fill, np.uint8)
+        for n in lengths:
+            assert _crc(const, n) == binascii.crc32(const[:n].tobytes()), \
+                (fill, n)
+    # Content past n must not leak into the checksum.
+    cut = min(100, K // 2)
+    buf2 = buf.copy()
+    buf2[cut:] ^= 0xFF
+    assert _crc(buf2, cut) == _crc(buf, cut)
+
+
+def test_crc32_bytes_compiles_once_per_length():
+    """`n` is traced: many lengths share one compiled graph per size."""
+    rng = _rng()
+    for K in (3000, MAX_BLOCK):
+        buf = rng.integers(0, 256, K, np.uint8)
+        _crc(buf, K)
+        graphs = ops.crc32_bytes._cache_size()
+        for n in range(0, K + 1, max(1, K // 17)):
+            assert _crc(buf, n) == binascii.crc32(buf[:n].tobytes()), (K, n)
+        assert ops.crc32_bytes._cache_size() == graphs, K
+
+
+@pytest.mark.parametrize("K", [4096, MAX_BLOCK])
+def test_crc32_bytes_vmapped_rows(K):
+    """The `plan_decode` use: one CRC per row of a vmapped micro-batch."""
+    import jax
     import jax.numpy as jnp
 
     rng = _rng()
-    cap = 4096
-    buf = rng.integers(0, 256, cap, np.uint8)
-    for n in (0, 1, 3, 7, 8, 9, 15, 16, 255, 256, 257, 1000, cap - 1, cap):
-        got = int(ops.crc32_bytes(jnp.asarray(buf), jnp.int32(n)))
-        want = binascii.crc32(buf[:n].tobytes()) & 0xFFFFFFFF
-        assert got == want, n
-    # Full 64 KB block (the decode row shape) and an all-zero run.
-    big = rng.integers(0, 256, MAX_BLOCK, np.uint8)
-    assert int(ops.crc32_bytes(jnp.asarray(big), jnp.int32(MAX_BLOCK))) == \
-        binascii.crc32(big.tobytes()) & 0xFFFFFFFF
-    zeros = np.zeros(MAX_BLOCK, np.uint8)
-    assert int(ops.crc32_bytes(jnp.asarray(zeros), jnp.int32(70))) == \
-        binascii.crc32(bytes(70)) & 0xFFFFFFFF
-    # Content past n must not leak into the checksum.
-    buf2 = buf.copy()
-    buf2[100:] ^= 0xFF
-    assert int(ops.crc32_bytes(jnp.asarray(buf2), jnp.int32(100))) == \
-        int(ops.crc32_bytes(jnp.asarray(buf), jnp.int32(100)))
+    rows = rng.integers(0, 256, (6, K), np.uint8)
+    ns = np.array([0, 1, 1023, 1025, K - 1, K], np.int32)
+    got = jax.jit(jax.vmap(ops.crc32_bytes))(jnp.asarray(rows),
+                                             jnp.asarray(ns))
+    want = [binascii.crc32(r[:n].tobytes()) for r, n in zip(rows, ns)]
+    assert [int(g) for g in got] == want

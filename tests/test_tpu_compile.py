@@ -7,8 +7,8 @@ max_match 36, pws 8), so a change the chip's compiler would refuse fails
 here instead of on the chip:
 
   * the engines' default graphs: the vmapped write graph at micro_batch 32,
-    and the device decode graphs (`decode_gather`, `plan_decode`) at
-    micro_batch 8;
+    the device decode graphs (`decode_gather`, `plan_decode`) at
+    micro_batch 8, and the in-graph CRC-32 of one decoded row;
   * the Pallas kernels with ``interpret=False``: every kernel the resolver
     (`repro.kernels.backend`) lets run on a TPU compiles to a Mosaic custom
     call, and every kernel it refuses is still refused by the compiler.
@@ -102,6 +102,14 @@ def test_device_decode_graph_compiles(one_chip, graph):
     c = fn.lower(*args).compile()
     out = c.out_info if graph == "decode_gather" else c.out_info[0]
     assert out.shape == (m, CAPS.out_cap)
+
+
+def test_device_crc_graph_compiles(one_chip):
+    """The in-graph CRC-32 of a decoded 64 KB row (the verified restore's
+    per-block check), with its byte count traced."""
+    c = ops.crc32_bytes.lower(_spec(one_chip, (CAPS.out_cap,), jnp.uint8),
+                              _spec(one_chip, ())).compile()
+    assert c.out_info.shape == () and c.out_info.dtype == jnp.uint32
 
 
 def test_fabric_write_graph_compiles_on_four_chips(topo, one_chip):
