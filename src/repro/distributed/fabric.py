@@ -21,10 +21,12 @@ on the same slice (the fabric's core invariant, asserted by
 Two execution paths, bit-identical by construction:
 
   * **mesh path** (`mesh` with >1 shard): one global
-    ``(S*r, MAX_BLOCK+_PAD)`` stack per step, `shard_map` splits it along
-    the shard axes, every shard compresses its ``r`` rows concurrently,
-    and the two-step sliced drain fetches the compressed payload bytes
-    (rounded up to `engine.FETCH_QUANTUM`).  Decode mirrors it: host
+    ``(S*r, MAX_BLOCK+_PAD)`` stack per step, put straight onto its shards
+    (each chip receives only its ``r`` rows), every shard compresses its
+    rows concurrently under `shard_map`, and the two-step sliced drain
+    fetches each compressed payload (rounded up to `engine.FETCH_QUANTUM`)
+    from the chip that holds its row, through the same single-device
+    slice program the one-chip engine uses.  Decode mirrors it: host
     planning (`plan_block_fast` -> `to_device_plan`) stacks fixed-shape
     `DevicePlan`s per shard and one `shard_map`(vmap(`decode_gather`))
     dispatch resolves every block.
@@ -35,10 +37,15 @@ Two execution paths, bit-identical by construction:
 
 Spans (`repro.obs`): the fabric reuses the engine's ``compress.pad`` /
 ``compress.dispatch`` / ``compress.wait`` / ``compress.drain`` stage names
-(with ``shards=`` attributes) and adds ``compress.shard`` (one per shard on
-the host path) and ``compress.merge`` — the per-stage table from
-`tools/trace_report.py` shows the merge cost directly.  Counters:
-``fabric.dispatches``, ``fabric.merged_blocks``, ``fabric.fallback_blocks``.
+(with ``shards=`` attributes; a mesh-path ``compress.drain`` carries the
+``shard=`` it fetched from) and adds ``fabric.put`` (the put of a step's
+stack onto its shards), ``compress.shard`` (one per shard on the host path)
+and ``compress.merge`` — the per-stage table from `tools/trace_report.py`
+shows the merge cost directly.  Counters: ``fabric.dispatches``,
+``fabric.merged_blocks``, ``fabric.drain_bytes`` (payload bytes the mesh
+path fetched to the host), ``fabric.fallback_blocks``.  In a profiler trace
+the sharded programs are ``jit_fabric_compress``, ``jit_fabric_decode`` and
+``jit_fabric_plan_decode``.
 
 See docs/architecture.md (fabric section) and docs/tuning.md (mesh-shape
 guidance) for when sharding pays.
@@ -51,6 +58,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
@@ -134,15 +142,17 @@ def _sharded_compress_compiled(mesh, shard_axes, hash_bits, max_match, pws,
         in_specs=(spec, spec), out_specs=(spec, spec),
         check_vma=False,
     )
-    return jax.jit(sm)
+
+    def fabric_compress(stack, ns):
+        return sm(stack, ns)
+
+    return jax.jit(fabric_compress)
 
 
-def _fetch_payload(st, sp, out_dev, row: int, size: int) -> bytes:
-    """Slice-fetch ``size`` compressed bytes of one stacked row."""
-    with sp("compress.drain", bytes=size):
-        data, moved = fetch_row_prefix(out_dev, row, size)
-    st.host_bytes += moved
-    return data
+def _shard_pieces(out_dev, r: int) -> dict:
+    """Shard index -> the single-device ``(r, ...)`` piece of a row-sharded
+    global array that holds rows ``[i*r, (i+1)*r)``."""
+    return {(s.index[0].start or 0) // r: s.data for s in out_dev.addressable_shards}
 
 
 def _mesh_collect(engine, chunks, slices, st, sp):
@@ -152,8 +162,11 @@ def _mesh_collect(engine, chunks, slices, st, sp):
     stack is ``(S*r, MAX_BLOCK+_PAD)`` with shard i owning rows
     ``[i*r, (i+1)*r)`` (``r`` power-of-two-padded so compiled shapes stay
     bounded; rows past a shard's slice carry n=0 and are never drained).
-    Dispatch is double-buffered like the single-device engine: step k+1 is
-    stacked and dispatched before the host syncs on step k's size vector.
+    The stack is put straight onto its shards, and each payload is fetched
+    from the shard's own single-device piece of the output, so neither the
+    put nor the drain moves a row between chips.  Dispatch is
+    double-buffered like the single-device engine: step k+1 is stacked and
+    dispatched before the host syncs on step k's size vector.
     """
     per = [chunks[sl.start: sl.stop] for sl in slices]
     S = len(per)
@@ -164,7 +177,18 @@ def _mesh_collect(engine, chunks, slices, st, sp):
         engine.max_match, engine.pws, engine.use_pallas, engine.scan_impl,
         engine.candidate_impl,
     )
+    to_mesh = NamedSharding(engine.mesh, P(tuple(engine.shard_axes)))
+    drained = obs.registry().counter(
+        "fabric.drain_bytes", "payload bytes the mesh path fetched to the host",
+    ) if engine._obs_on() else obs.NOOP_METRIC
     out_lists: list[list] = [[] for _ in range(S)]
+
+    def fetch(piece, shard: int, j: int, size: int) -> bytes:
+        with sp("compress.drain", bytes=size, shard=shard):
+            data, moved = fetch_row_prefix(piece, j, size)
+        st.host_bytes += moved
+        drained.inc(moved)
+        return data
 
     def drain(meta, res):
         start, counts, r = meta
@@ -172,14 +196,14 @@ def _mesh_collect(engine, chunks, slices, st, sp):
         with sp("compress.wait", rows=sum(counts), shards=S):
             sizes = jax.device_get(size_dev)
         st.host_bytes += sizes.nbytes
+        pieces = _shard_pieces(out_dev, r)
         for i, cnt in enumerate(counts):
             for j in range(cnt):
-                row = i * r + j
                 chunk = per[i][start + j]
-                size = int(sizes[row])
+                size = int(sizes[i * r + j])
                 out_lists[i].append((chunk, len(chunk), size,
-                                     functools.partial(_fetch_payload, st, sp,
-                                                       out_dev, row, size)))
+                                     functools.partial(fetch, pieces[i], i, j,
+                                                       size)))
 
     inflight = None
     for start in range(0, steps, mb):
@@ -194,10 +218,12 @@ def _mesh_collect(engine, chunks, slices, st, sp):
                     row = i * r + j
                     stack[row, : len(c)] = np.frombuffer(c, np.uint8)
                     ns[row] = len(c)
+        with sp("fabric.put", bytes=stack.nbytes, shards=S):
+            stack_dev, ns_dev = jax.device_put((stack, ns), to_mesh)
         st.dispatches += 1
         with sp("compress.dispatch", rows=sum(counts), shards=S,
                 impl=engine.candidate_impl):
-            res = fn(jnp.asarray(stack), jnp.asarray(ns))
+            res = fn(stack_dev, ns_dev)
         if inflight is not None:
             drain(*inflight)
         inflight = ((start, counts, r), res)
@@ -326,7 +352,11 @@ def _sharded_decode_compiled(mesh, shard_axes, out_cap, rounds, use_pallas):
         in_specs=(spec,) * 9, out_specs=spec,
         check_vma=False,
     )
-    return jax.jit(sm)
+
+    def fabric_decode(*args):
+        return sm(*args)
+
+    return jax.jit(fabric_decode)
 
 
 @functools.lru_cache(maxsize=None)
@@ -348,7 +378,11 @@ def _sharded_plan_decode_compiled(mesh, shard_axes, out_cap, max_lit,
         in_specs=(spec,) * 3, out_specs=(spec, spec, spec),
         check_vma=False,
     )
-    return jax.jit(sm)
+
+    def fabric_plan_decode(blk, ns, max_out):
+        return sm(blk, ns, max_out)
+
+    return jax.jit(fabric_plan_decode)
 
 
 def _round_bucket(rounds: int) -> int:
