@@ -17,7 +17,9 @@ This is the TPU-native re-expression of the hardware architecture in Fig. 5:
         CPU, the expected accelerator shapes off-CPU.
   Match Searching                -> vectorized word compare (the table stores
         the 4-byte string; here: words[cand] == words[p])
-  Extended Match (bounded, S2)   -> kernels.ops.match_lengths (fixed-depth)
+  Extended Match (bounded, S2)   -> kernels.ops.confirmed_match_lengths: the
+        confirm and the extension compare 4-byte words, the candidate's
+        from one gather per 8 words (kernels.ref.match_words_ref)
   single-match select (S1)       -> per-window earliest-eligible selection.
         The only true sequential state is the free pointer; S2 bounds its
         reach to max_match-1 bytes, so it admits BOTH
@@ -51,7 +53,6 @@ from .lz4_types import (
     DEFAULT_PWS,
     LAST_LITERALS,
     MAX_BLOCK,
-    MF_LIMIT,
     MIN_MATCH,
     Sequence,
 )
@@ -319,7 +320,6 @@ def compress_block_records(
     # Each stage runs under a `jax.named_scope` ("lz4.<stage>"), so the
     # per-operation events of a device trace name the stage they belong to;
     # the scope names are stable (docs/observability.md lists them).
-    p = jnp.arange(MAX_BLOCK, dtype=jnp.int32)
     if candidate_impl == "fused":
         # Single-pass datapath: hash, LVT candidate, word compare, and the
         # bounded extension come back from ONE kernel (or its jnp twin) —
@@ -333,8 +333,8 @@ def compress_block_records(
             valid = lengths >= MIN_MATCH
     else:
         with jax.named_scope("lz4.hash"):
-            words, hashes = ops.hash_positions(block[: MAX_BLOCK + 3], hash_bits,
-                                               use_pallas=use_pallas)
+            _, hashes = ops.hash_positions(block[: MAX_BLOCK + 3], hash_bits,
+                                           use_pallas=use_pallas)
         cand_fn = {
             "sort": _candidates,
             "sortkey": _candidates_sortkey,
@@ -344,12 +344,9 @@ def compress_block_records(
             cand = cand_fn(hashes, n, hash_bits, pws)
 
         with jax.named_scope("lz4.extend"):
-            has_cand = cand >= 0
-            wc = jnp.take(words, jnp.clip(cand, 0, MAX_BLOCK - 1))
-            valid4 = has_cand & (wc == words) & (p <= n - MF_LIMIT)
-            lengths = ops.match_lengths(block, cand, valid4, n, max_match=max_match,
-                                        use_pallas=use_pallas)
-            valid = valid4 & (lengths >= MIN_MATCH)
+            lengths = ops.confirmed_match_lengths(block, cand, n, max_match=max_match,
+                                                  use_pallas=use_pallas)
+            valid = lengths >= MIN_MATCH
 
     with jax.named_scope("lz4.select"):
         if scan_impl == "sequential":

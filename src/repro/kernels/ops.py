@@ -77,6 +77,24 @@ def match_lengths(block_i32, cand, valid, n, max_match: int = 36, use_pallas: bo
     return ref.match_extend_ref(block_i32, cand, valid, n, max_match)
 
 
+@functools.partial(jax.jit, static_argnames=("max_match", "use_pallas"))
+def confirmed_match_lengths(block_i32, cand, n, max_match: int = 36,
+                            use_pallas: bool = False):
+    """The write graph's S2 stage: 4-byte confirm, then bounded extension.
+
+    Full match length per position: 0 where no match starts at p (no
+    candidate, its first 4 bytes differ, or p > n - MF_LIMIT), else in
+    [4, max_match].  Both paths confirm with `ref.match_words_ref`; the jnp
+    path takes the extension from the same word compare, `use_pallas` from
+    the byte-wise `match_extend` kernel.
+    """
+    start, extra = ref.match_words_ref(block_i32, cand, n, max_match)
+    if use_pallas:
+        return match_lengths(block_i32, cand, start, n, max_match=max_match,
+                             use_pallas=True)
+    return jnp.where(start, MIN_MATCH + extra, 0)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("positions", "hash_bits", "pws", "max_match", "use_pallas"),

@@ -35,29 +35,82 @@ def fibhash_ref(b0, b1, b2, b3, hash_bits: int):
     return w.astype(jnp.int32), h.astype(jnp.int32)
 
 
-def match_extend_ref(block, cand, valid, n, max_match: int):
-    """Bounded extended-match length (the paper's feedforward S2 datapath).
+WORD = 4  # bytes the bounded extension compares per step
+# Ladder rungs per candidate-side gather: 8 rows fill a TPU (8, 128) tile,
+# so no gathered tile pads to 16 rows.  On a TPU v5e one 9-row gather ran
+# slower than an 8-row and a 1-row one, and asked 44% more temporary memory.
+GATHER_RUNGS = 8
+
+
+def word_ladder(block, positions: int, rungs: int):
+    """The word ladder: (rungs + 1, positions) uint32, rung k at position q
+    the little-endian 4-byte word at byte q + 4k.
+
+    Built from static slices of the block, which is zero-padded here if it
+    ends before the last word does.
+    """
+    L = positions + WORD * rungs
+    b = block.astype(jnp.uint32)
+    if b.shape[0] < L + 3:
+        b = jnp.pad(b, (0, L + 3 - b.shape[0]))
+    words = b[:L] | (b[1 : L + 1] << 8) | (b[2 : L + 2] << 16) | (b[3 : L + 3] << 24)
+    return jnp.stack([words[WORD * k : WORD * k + positions] for k in range(rungs + 1)])
+
+
+def match_words_ref(block, cand, n, max_match: int):
+    """4-byte confirm and bounded extension, a word at a time (the S2 datapath).
 
     block : (B,) int32 byte values (padded past `n` arbitrarily)
-    cand  : (P,) int32 candidate position for each position p (garbage if ~valid)
-    valid : (P,) bool  4-byte match already confirmed at p
+    cand  : (P,) int32 candidate position per position p (-1 where none)
     n     : scalar int32, true block length
     max_match : static python int, the match-length cap (paper: 36)
 
-    Returns (P,) int32 full match length (>= 4 where valid, 0 elsewhere),
-    capped at max_match and at the end-of-block rule (match end <= n-5).
+    Position p's own ladder column holds its first four bytes (rung 0) and
+    the K = ceil((max_match - 4) / 4) words after them; the candidate's
+    column comes from one gather of the ladder at `cand` per GATHER_RUNGS
+    rungs (two at max_match 36), in place of a gather per byte.  A word
+    adds 4 while every earlier word was equal; the first unequal one adds
+    its count of equal low-order bytes.
+
+    Returns ``(start, extra)``, both (P,): ``start`` is True where a match
+    can start at p (a candidate whose first 4 bytes equal p's, and
+    p <= n - MF_LIMIT); ``extra`` is the count of equal bytes after those
+    four, ``min(first mismatch, max_extra)`` with ``max_extra`` the cap of
+    ``max_match - 4`` and of the end-of-block rule (match end <= n - 5).
+    Bytes past the cap never change it.
     """
     P = cand.shape[0]
+    K = -(-(max_match - MIN_MATCH) // WORD)
+    ladder = word_ladder(block, P, K)
+    c = jnp.clip(cand, 0, P - 1)
+    x = []  # the K + 1 rungs of ladder ^ candidate's ladder column
+    for g in range(0, K + 1, GATHER_RUNGS):
+        part = ladder[g : g + GATHER_RUNGS]
+        x.extend(part ^ jnp.take(part, c, axis=1))
     p = jnp.arange(P, dtype=jnp.int32)
+    start = (cand >= 0) & (x[0] == 0) & (p <= n - MF_LIMIT)
+    run = jnp.zeros(P, dtype=jnp.int32)
+    alive = jnp.ones(P, dtype=bool)
+    for k in range(1, K + 1):
+        # equal low-order bytes: the masks nest, so each equal byte adds 1
+        low = sum(((x[k] & m) == 0).astype(jnp.int32) for m in (0xFF, 0xFFFF, 0xFFFFFF))
+        low = low + (x[k] == 0).astype(jnp.int32)
+        run = run + jnp.where(alive, low, 0)
+        alive = alive & (x[k] == 0)
     max_extra = jnp.clip(n - LAST_LITERALS - (p + MIN_MATCH), 0, max_match - MIN_MATCH)
-    prefix = jnp.ones(P, dtype=bool)
-    length = jnp.zeros(P, dtype=jnp.int32)
-    for j in range(max_match - MIN_MATCH):
-        cur = block[jnp.clip(p + MIN_MATCH + j, 0, block.shape[0] - 1)]
-        cnd = block[jnp.clip(cand + MIN_MATCH + j, 0, block.shape[0] - 1)]
-        prefix = prefix & (cur == cnd) & (j < max_extra)
-        length = length + prefix.astype(jnp.int32)
-    return jnp.where(valid, MIN_MATCH + length, 0)
+    return start, jnp.minimum(run, max_extra)
+
+
+def match_extend_ref(block, cand, valid, n, max_match: int):
+    """Bounded extended-match length where a 4-byte match is given.
+
+    valid : (P,) bool, 4-byte match already confirmed at p; the other
+    arguments as `match_words_ref`.  Returns (P,) int32 full match length
+    (>= 4 where valid, 0 elsewhere), capped at max_match and at the
+    end-of-block rule (match end <= n-5).
+    """
+    _, extra = match_words_ref(block, cand, n, max_match)
+    return jnp.where(valid, MIN_MATCH + extra, 0)
 
 
 def scatter_candidates_ref(hashes, n, hash_bits: int, pws: int):
@@ -114,13 +167,10 @@ def fused_ref(block, n, positions: int, hash_bits: int, pws: int,
     b1 = block[1 : P + 1]
     b2 = block[2 : P + 2]
     b3 = block[3 : P + 3]
-    words, hashes = fibhash_ref(b0, b1, b2, b3, hash_bits)
-    p = jnp.arange(P, dtype=jnp.int32)
+    _, hashes = fibhash_ref(b0, b1, b2, b3, hash_bits)
     cand = scatter_candidates_ref(hashes, n, hash_bits, pws)
-    wc = jnp.take(words, jnp.clip(cand, 0, P - 1))
-    valid4 = (cand >= 0) & (wc == words) & (p <= n - MF_LIMIT)
-    lengths = match_extend_ref(block, cand, valid4, n, max_match)
-    return cand, lengths
+    start, extra = match_words_ref(block, cand, n, max_match)
+    return cand, jnp.where(start, MIN_MATCH + extra, 0)
 
 
 def emit_bytes_ref(block, seg, fields, total):

@@ -34,6 +34,8 @@ def _rng():
 
 def _adversarial_corpus() -> dict[str, bytes]:
     """Random + adversarial blocks aimed at emit-layout edge cases."""
+    from tests.test_lz4_jax import _word_lane_data
+
     rng = _rng()
     return {
         "empty": b"",
@@ -57,6 +59,8 @@ def _adversarial_corpus() -> dict[str, bytes]:
         "text": b"the quick brown fox jumps over the lazy dog. " * 1000,
         "low_entropy": rng.integers(0, 4, MAX_BLOCK, np.uint8).tobytes(),
         "structured": bytes(rng.integers(0, 16, 64, np.uint8)) * 1024,
+        # matches ending on every word lane of the extension and at its cap
+        "word_lanes": _word_lane_data(),
     }
 
 

@@ -1,11 +1,14 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, exact equality."""
+import functools
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core.lz4_types import HASH_PRIME
+from repro.core.jax_compressor import _PAD
+from repro.core.lz4_types import HASH_PRIME, LAST_LITERALS, MAX_BLOCK, MF_LIMIT, MIN_MATCH
 from repro.kernels import ops
-from repro.kernels.ref import fibhash_ref, match_extend_ref
 
 
 def _np_hash(words: np.ndarray, bits: int) -> np.ndarray:
@@ -88,6 +91,122 @@ def test_match_extend_end_of_block_cap():
     p = np.arange(n)
     expected = 4 + np.clip(n - 5 - (p + 4), 0, 32)
     np.testing.assert_array_equal(out, expected)
+
+
+# --- word-wide extension vs the byte-at-a-time definition --------------------
+
+@functools.partial(jax.jit, static_argnames="max_match")
+def _match_extend_bytes(block, cand, valid, n, max_match):
+    """The bounded extension one byte per step: the definition the word-wide
+    `ops.match_lengths` must equal (the device path until word compares)."""
+    P = cand.shape[0]
+    p = jnp.arange(P, dtype=jnp.int32)
+    max_extra = jnp.clip(n - LAST_LITERALS - (p + MIN_MATCH), 0, max_match - MIN_MATCH)
+    prefix = jnp.ones(P, dtype=bool)
+    length = jnp.zeros(P, dtype=jnp.int32)
+    for j in range(max_match - MIN_MATCH):
+        cur = block[jnp.clip(p + MIN_MATCH + j, 0, block.shape[0] - 1)]
+        cnd = block[jnp.clip(cand + MIN_MATCH + j, 0, block.shape[0] - 1)]
+        prefix = prefix & (cur == cnd) & (j < max_extra)
+        length = length + prefix.astype(jnp.int32)
+    return jnp.where(valid, MIN_MATCH + length, 0)
+
+
+def _extension_case(kind: str, max_match: int):
+    """(block, cand, valid, n): one block, or a stack of 32 for "vmapped"."""
+    rng = np.random.default_rng(max_match * 100 + len(kind))
+    if kind == "lanes":
+        # One (candidate, position) pair per byte offset 4 .. max_match + 1
+        # from the match start, each with its first mismatch there: every
+        # byte lane of every compared word, the cap, and one byte past it.
+        n = 256 * (max_match - 1)
+        block = rng.integers(0, 256, n + max_match, dtype=np.int32)
+        cand = rng.integers(0, n, n, dtype=np.int32)
+        valid = rng.random(n) < 0.5
+        for i, d in enumerate(range(MIN_MATCH, max_match + 2)):
+            q, p = 256 * i, 256 * i + 128
+            block[p: p + max_match + 8] = block[q: q + max_match + 8]
+            block[p + d] ^= 0x5A
+            cand[p], valid[p] = q, True
+        return block, cand, valid, n
+    if kind == "end_of_block":
+        # n of 12-16 and MAX_BLOCK, all zeros: the end-of-block rule caps
+        # every length (stacked below as one case per n).
+        out = []
+        for n in (12, 13, 14, 15, 16, MAX_BLOCK):
+            P = n
+            block = np.zeros(n + max_match, np.int32)
+            p = np.arange(P, dtype=np.int32)
+            out.append((block, np.maximum(p - 1 - (p % 3), 0).astype(np.int32),
+                        np.ones(P, bool), n))
+        return out
+    if kind == "rle":
+        # cand = p - 1: the candidate overlaps the current bytes (runs).
+        n = 8192
+        runs = np.repeat(rng.integers(0, 4, 256), rng.integers(1, 80, 256))
+        block = np.zeros(n + max_match, np.int32)
+        block[:n] = np.resize(runs, n)
+        p = np.arange(n, dtype=np.int32)
+        return block, p - 1, p >= 1, n
+    if kind == "no_cand":
+        n = 2048
+        block = rng.integers(0, 2, n + max_match, dtype=np.int32)
+        return block, np.full(n, -1, np.int32), np.zeros(n, bool), n
+    assert kind == "vmapped"
+    # The write graph's shape: 32 padded blocks, zeroed past n, nearby
+    # candidates (-1 where none).
+    ns = rng.integers(MAX_BLOCK - 4096, MAX_BLOCK + 1, 32).astype(np.int32)
+    ns[:3] = (MAX_BLOCK, 13, 0)
+    block = rng.integers(0, 4, (32, MAX_BLOCK + _PAD), dtype=np.int32)
+    block[np.arange(MAX_BLOCK + _PAD)[None, :] >= ns[:, None]] = 0
+    p = np.arange(MAX_BLOCK, dtype=np.int32)
+    cand = p[None, :] - 1 - rng.integers(0, 2048, (32, MAX_BLOCK))
+    cand = np.where(cand < 0, -1, cand).astype(np.int32)
+    return block, cand, cand >= 0, ns
+
+
+def _confirmed(block, cand, n):
+    """Where a match starts: a candidate whose first 4 bytes equal p's."""
+    d = block.astype(np.int64)
+    word = lambda q: d[q] | d[q + 1] << 8 | d[q + 2] << 16 | d[q + 3] << 24  # noqa: E731
+    p = np.arange(cand.shape[0])
+    c = np.clip(cand, 0, len(p) - 1)
+    return (cand >= 0) & (word(c) == word(p)) & (p <= n - MF_LIMIT)
+
+
+@pytest.mark.parametrize("max_match", [12, 20, 36, 68])
+@pytest.mark.parametrize("kind", ["lanes", "end_of_block", "rle", "no_cand", "vmapped"])
+def test_match_lengths_words_equal_byte_loop(kind, max_match):
+    """The word-wide extension (`ops.match_lengths`, jnp path) equals the
+    byte-at-a-time definition, and the write graph's confirm + extension
+    (`ops.confirmed_match_lengths`) equals it at the confirmed positions."""
+    case = _extension_case(kind, max_match)
+    if kind == "vmapped":
+        block, cand, valid, n = (jnp.asarray(a) for a in case)
+        words = jax.vmap(functools.partial(ops.match_lengths, max_match=max_match))
+        bytes_ = jax.vmap(functools.partial(_match_extend_bytes, max_match=max_match))
+        got, want = words(block, cand, valid, n), bytes_(block, cand, valid, n)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        start = np.stack([_confirmed(*a) for a in zip(case[0], case[1], case[3])])
+        conf = jax.vmap(functools.partial(ops.confirmed_match_lengths, max_match=max_match))
+        np.testing.assert_array_equal(np.asarray(conf(block, cand, n)),
+                                      np.asarray(bytes_(block, cand, jnp.asarray(start), n)))
+        return
+    for block, cand, valid, n in (case if kind == "end_of_block" else [case]):
+        args = (jnp.asarray(block), jnp.asarray(cand))
+        got = ops.match_lengths(*args, jnp.asarray(valid), n, max_match=max_match)
+        want = _match_extend_bytes(*args, jnp.asarray(valid), n, max_match=max_match)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want), err_msg=f"n={n}")
+        start = jnp.asarray(_confirmed(block, cand, n))
+        np.testing.assert_array_equal(
+            np.asarray(ops.confirmed_match_lengths(*args, n, max_match=max_match)),
+            np.asarray(_match_extend_bytes(*args, start, n, max_match=max_match)),
+            err_msg=f"n={n}")
+    if kind == "lanes":
+        # the planted pairs: the first mismatch at offset d gives length d,
+        # up to the cap
+        for i, d in enumerate(range(MIN_MATCH, max_match + 2)):
+            assert int(got[256 * i + 128]) == min(d, max_match), d
 
 
 # --- execution mode per backend (repro.kernels.backend) ----------------------

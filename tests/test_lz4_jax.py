@@ -15,6 +15,20 @@ from repro.core.jax_compressor import (
 from repro.core.schemes import compress_windowed
 
 
+def _word_lane_data() -> bytes:
+    """Copies of one random string cut one byte longer each time (3 to 41
+    bytes, then a flipped byte), so matches end on every byte lane of every
+    compared word, at the 36-byte cap and past it; RLE runs between them."""
+    rng = np.random.default_rng(18)
+    base = rng.integers(0, 256, 48, np.uint8).tobytes()
+    out = [base]
+    for L in range(3, 42):
+        out.append(base[:L] + bytes([base[L] ^ 0xFF])
+                   + rng.integers(0, 256, 9, np.uint8).tobytes()
+                   + bytes([L]) * (L + 2))
+    return b"".join(out)
+
+
 def _datasets():
     rng = np.random.default_rng(42)
     out = {
@@ -28,6 +42,7 @@ def _datasets():
         "empty": b"",
         "block_64k": rng.integers(0, 16, 65536, dtype=np.uint8).tobytes(),
         "self_overlap": b"a" * 3000 + b"xyz" + b"a" * 3000,
+        "word_lanes": _word_lane_data(),
     }
     return out
 
