@@ -14,11 +14,7 @@ Also records, per path:
     this is the acceptance metric for device-side emission;
   * emit-stage throughput: the host emitter timed alone on pre-fetched
     records, vs the device path's fused emit (reported as the marginal
-    pipeline cost, since in-graph emission cannot be timed separately);
-  * the `candidate_impl` sweep (sort / sortkey / scatter / fused / auto) at
-    the default micro-batch: all five produce byte-identical frames, and
-    the fastest non-sort impl beating "sort" is the acceptance metric for
-    retiring the 64K-element candidate sort (`best_non_sort_vs_sort_x`).
+    pipeline cost, since in-graph emission cannot be timed separately).
 
 JSON lands in experiments/benchmarks/engine_batched.json and is mirrored to
 BENCH_engine_batched.json at the repo root so the perf trajectory is easy to
@@ -29,7 +25,6 @@ from __future__ import annotations
 
 import json
 import os
-import time
 
 from repro.core import LZ4Engine, decode_frame
 from repro.core.lz4_types import MAX_BLOCK
@@ -82,11 +77,7 @@ def run(fast: bool = True) -> dict:
         for i in range(0, len(data), MAX_BLOCK):
             chunk = data[i: i + MAX_BLOCK]
             buf, n = pad_block(chunk)
-            # candidate_impl pinned to the historical "sort" — this column
-            # reconstructs the PRE-refactor path; letting it float with the
-            # "auto" default would silently redefine the baseline.
-            rec = compress_block_records(jnp.asarray(buf), jnp.int32(n),
-                                         candidate_impl="sort")
+            rec = compress_block_records(jnp.asarray(buf), jnp.int32(n))
             blocks.append(encode_block(chunk, records_to_plan(rec, n)))
         return blocks
 
@@ -94,18 +85,13 @@ def run(fast: bool = True) -> dict:
     out["serial_blocks_per_s"] = round(n_blocks / dt, 2)
     out["serial_mbps"] = round(len(data) / dt / 1e6, 2)
 
-    # Both engine emission paths over the micro-batch sweep.  "batch" and
-    # "device_emit" keep their historical meaning — records + host emit vs
-    # in-graph emit, BOTH pinned to candidate_impl="sort" — so the columns
-    # stay diffable against older BENCH_engine_batched.json baselines; the
-    # "candidate_impl" section below is where impl choice (incl. the
-    # "auto" default) is measured.
+    # Both engine emission paths over the micro-batch sweep: "batch" is
+    # records + host emit, "device_emit" the in-graph emit.
     ref_frame = None
     for key, device_emit in (("batch", False), ("device_emit", True)):
         out[key] = {}
         for b in sizes:
-            eng = LZ4Engine(micro_batch=b, device_emit=device_emit,
-                            candidate_impl="sort")
+            eng = LZ4Engine(micro_batch=b, device_emit=device_emit)
             frame = eng.compress(data)
             assert decode_frame(frame) == data, "engine round-trip failed"
             if ref_frame is None:
@@ -129,8 +115,7 @@ def run(fast: bool = True) -> dict:
     mb = str(min(32, max(sizes)))
     records_bytes = out["batch"][mb]["host_bytes"]
     device_bytes = out["device_emit"][mb]["host_bytes"]
-    full_eng = LZ4Engine(micro_batch=int(mb), drain="full",
-                         candidate_impl="sort")
+    full_eng = LZ4Engine(micro_batch=int(mb), drain="full")
     assert full_eng.compress(data) == ref_frame
     full_bytes = full_eng.stats.host_bytes
     out["host_transfer"] = {
@@ -142,44 +127,6 @@ def run(fast: bool = True) -> dict:
         "sliced_vs_full_drain_x": round(full_bytes / device_bytes, 3),
     }
 
-    # Candidate-resolution sweep (PR 5): the four bit-identical impls plus
-    # the "auto" default, on the default micro-batch and emission path.
-    # "sort" is the pre-PR-5 default (full 64K-element argsort per block);
-    # "fused" runs the single-pass datapath — here via its jnp twin, since
-    # interpret-mode Pallas is a correctness tool, not a CPU fast path.
-    # Configs are timed INTERLEAVED (one rep each per round, min over
-    # rounds) so CPU-frequency noise hits every impl equally — "auto" must
-    # read like the impl it resolved to, not like whichever config drew
-    # the thermal short straw.
-    out["candidate_impl"] = {"micro_batch": int(mb)}
-    sweep = ("sort", "sortkey", "scatter", "fused", "auto")
-    sweep_engines = {}
-    for impl in sweep:
-        eng = LZ4Engine(micro_batch=int(mb), candidate_impl=impl)
-        frame = eng.compress(data)  # warmup/jit + frame-identity check
-        assert frame == ref_frame, f"candidate_impl={impl} frame differs"
-        sweep_engines[impl] = eng
-    sweep_best = {impl: float("inf") for impl in sweep}
-    for _ in range(repeat + 2):
-        for impl in sweep:
-            t0 = time.perf_counter()
-            sweep_engines[impl].compress(data)
-            sweep_best[impl] = min(sweep_best[impl],
-                                   time.perf_counter() - t0)
-    for impl in sweep:
-        out["candidate_impl"][impl] = {
-            "blocks_per_s": round(n_blocks / sweep_best[impl], 2),
-            "mbps": round(len(data) / sweep_best[impl] / 1e6, 2),
-            "resolved": sweep_engines[impl].stats.candidate_impl,
-        }
-    best_bps, best_impl = max(
-        (out["candidate_impl"][i]["blocks_per_s"], i)
-        for i in ("sortkey", "scatter", "fused")
-    )
-    out["candidate_impl"]["best_non_sort"] = best_impl
-    out["candidate_impl"]["best_non_sort_vs_sort_x"] = round(
-        best_bps / out["candidate_impl"]["sort"]["blocks_per_s"], 3)
-
     # Emit-stage throughput.  The host emitter can be timed in isolation
     # (records pre-fetched); the device emitter is fused into the dispatch,
     # so its cost shows up as the pipeline delta between the two paths.
@@ -189,8 +136,7 @@ def run(fast: bool = True) -> dict:
     for i in range(0, len(data), MAX_BLOCK):
         chunk = data[i: i + MAX_BLOCK]
         buf, n = pad_block(chunk)
-        rec = compress_block_records(jnp.asarray(buf), jnp.int32(n),
-                                     candidate_impl="sort")
+        rec = compress_block_records(jnp.asarray(buf), jnp.int32(n))
         recs.append((chunk, np.asarray(rec.emit), np.asarray(rec.pos),
                      np.asarray(rec.length), np.asarray(rec.offset), n))
 

@@ -38,14 +38,6 @@ def run(fast: bool = True) -> dict:
             repeat=3,
         )
         out[f"cpu_mbps_{impl}"] = round(MAX_BLOCK / dt / 1e6, 2)
-    for cand in ("sortkey", "scatter", "fused"):
-        _, dt = timed(
-            lambda: compress_block_records(
-                buf_j, n_j, scan_impl="associative", candidate_impl=cand
-            ).size.block_until_ready(),
-            repeat=3,
-        )
-        out[f"cpu_mbps_cand_{cand}"] = round(MAX_BLOCK / dt / 1e6, 2)
 
     # End-to-end batched pipeline: micro-batched dispatch, vectorized
     # emission, frame output (and the round trip is free to check here).

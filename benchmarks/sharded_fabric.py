@@ -123,7 +123,7 @@ def fabric_check(mesh, data: bytes, repeat: int = 0) -> dict:
     # on an empty stack to read where its operands and results live.
     fn = fabric._sharded_compress_compiled(
         eng.mesh, tuple(eng.shard_axes), eng.hash_bits, eng.max_match,
-        eng.pws, eng.use_pallas, eng.scan_impl, eng.candidate_impl)
+        eng.pws, eng.scan_impl)
     stack = np.zeros((S, MAX_BLOCK + _PAD), np.uint8)
     ns = np.zeros((S,), np.int32)
     in_shardings = fn.lower(stack, ns).compile().input_shardings[0]

@@ -167,9 +167,7 @@ def write_read_phase(dev, data: bytes, seed: int) -> None:
     st = eng.stats
     log(f"write: {len(data)} B -> {len(frame)} B (ratio {len(data) / len(frame):.4f}), "
         f"{st.blocks} blocks, {st.raw_blocks} raw, {st.dispatches} dispatches, "
-        f"candidate_impl={st.candidate_impl}, host_bytes {st.host_bytes}, "
-        f"{COMPILES[0] - c0} compiles, {dt:.3f} s")
-    check(st.candidate_impl == "scatter", f"candidate_impl {st.candidate_impl!r} on TPU")
+        f"host_bytes {st.host_bytes}, {COMPILES[0] - c0} compiles, {dt:.3f} s")
 
     sample = sample_blocks(st.blocks, seed)
     t0 = time.perf_counter()

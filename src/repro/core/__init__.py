@@ -83,8 +83,4 @@ from .decode_engine import (  # noqa: F401
     default_decode_engine,
 )
 from .engine import EngineStats, LZ4Engine, default_engine  # noqa: F401
-from .jax_compressor import (  # noqa: F401
-    CANDIDATE_IMPLS,
-    resolve_candidate_impl,
-)
 from .corpus import corpus_blocks, corpus_files  # noqa: F401

@@ -43,8 +43,9 @@ embarrassingly parallel (Sitaridi et al., arXiv 1606.00519):
                    executor per block (counted in `fallback_blocks`).
                    With ``plan_on_device=True`` phase ONE moves in-graph
                    too: the speculative planner
-                   (`kernels.plan_speculative`, validated/compacted by
-                   `kernels.ops.plan_speculative`) parses the token
+                   (`kernels.ops.plan_speculative`: the parse of
+                   `kernels.ref.plan_fields_ref`, validated and
+                   compacted) parses the token
                    stream on device and `kernels.ops.plan_decode` fuses
                    plan + gather + CRC into a single dispatch — the last
                    host O(n) stage is gone, and `host_bytes == 0` on the
@@ -104,7 +105,7 @@ _EXECUTORS = ("serial", "thread", "process", "device")
 
 
 @functools.lru_cache(maxsize=None)
-def _device_decode_compiled(out_cap: int, rounds: int, use_pallas: bool):
+def _device_decode_compiled(out_cap: int, rounds: int):
     """Jitted vmap of the single-block decode graph, cached per static
     config (shared across engine instances; jit's own cache then keys on
     the stacked batch shape, bounded by the power-of-two padding)."""
@@ -112,15 +113,13 @@ def _device_decode_compiled(out_cap: int, rounds: int, use_pallas: bool):
 
     from repro.kernels.ops import decode_gather
 
-    fn = functools.partial(decode_gather, out_cap=out_cap, rounds=rounds,
-                           use_pallas=use_pallas)
+    fn = functools.partial(decode_gather, out_cap=out_cap, rounds=rounds)
     return jax.jit(jax.vmap(fn))
 
 
 @functools.lru_cache(maxsize=None)
 def _device_plan_decode_compiled(out_cap: int, max_lit: int, max_match: int,
-                                 rounds: int, use_pallas: bool,
-                                 compute_crc: bool):
+                                 rounds: int, compute_crc: bool):
     """Jitted vmap of the FUSED plan+decode(+CRC) graph (`kernels.ops.
     plan_decode`) — the speculative-planning twin of
     `_device_decode_compiled`.  One dispatch takes a stacked micro-batch of
@@ -133,7 +132,7 @@ def _device_plan_decode_compiled(out_cap: int, max_lit: int, max_match: int,
 
     fn = functools.partial(plan_decode, out_cap=out_cap, max_lit=max_lit,
                            max_match=max_match, rounds=rounds,
-                           use_pallas=use_pallas, compute_crc=compute_crc)
+                           compute_crc=compute_crc)
     return jax.jit(jax.vmap(fn))
 
 
@@ -314,7 +313,7 @@ class LZ4DecodeEngine:
 
     def __init__(self, workers: int | None = None, executor: str | None = None,
                  min_parallel_blocks: int = 2, two_phase: bool | None = None,
-                 micro_batch: int = 8, use_pallas: bool = False,
+                 micro_batch: int = 8,
                  caps: DevicePlanCaps | None = None,
                  adaptive_rounds: bool = True,
                  plan_on_device: bool = False,
@@ -361,15 +360,14 @@ class LZ4DecodeEngine:
             else "serial"
         self.min_parallel_blocks = min_parallel_blocks
         # Device-executor knobs (harmless elsewhere): blocks per vmapped
-        # dispatch, kernel selection, fixed plan-array caps, and whether
+        # dispatch, fixed plan-array caps, and whether
         # host planning computes exact wave depths so shallow micro-batches
         # compile fewer pointer-doubling rounds (vs the static worst case).
         self.micro_batch = micro_batch
-        self.use_pallas = use_pallas
         self.caps = caps or DevicePlanCaps()
         self.adaptive_rounds = adaptive_rounds
         # Speculative in-graph planning: parse the token stream ON DEVICE
-        # (kernels/plan_speculative.py) and fuse plan+execute(+CRC) into
+        # (kernels.ops.plan_speculative) and fuse plan+execute(+CRC) into
         # one dispatch per micro-batch — `plan_block_fast` runs only as the
         # per-block fallback for payloads/plans that overflow the caps.
         # `adaptive_rounds` has no effect on this path: with no host plan
@@ -611,8 +609,7 @@ class LZ4DecodeEngine:
             mat[0][j], mat[1][j] = dp.match_dst, dp.match_off
             scal[0][j], scal[1][j], scal[2][j] = dp.n_lit, dp.n_match, dp.out_size
             rounds = max(rounds, dp.n_waves)
-        fn = _device_decode_compiled(caps.out_cap, _round_bucket(rounds),
-                                     self.use_pallas)
+        fn = _device_decode_compiled(caps.out_cap, _round_bucket(rounds))
         st.dispatches += 1
         st.device_blocks += len(batch)
         with sp("decode.execute", rows=len(batch), executor="device",
@@ -680,7 +677,7 @@ class LZ4DecodeEngine:
             mo[j] = max_out
         fn = _device_plan_decode_compiled(caps.out_cap, caps.max_lit,
                                           caps.max_match, MAX_RESOLVE_ROUNDS,
-                                          self.use_pallas, compute_crc)
+                                          compute_crc)
         st.dispatches += 1
         with sp("decode.plan_device", rows=len(batch), executor="device",
                 crc=compute_crc):

@@ -1,8 +1,7 @@
 """Batched, device-resident compression pipeline: the `LZ4Engine`.
 
-The engine is the primary write-path API (`compress_bytes`, the original
-entry point, survives only as a deprecated wrapper).  It keeps the paper's
-feedback-free token pipeline batch-parallel end to end:
+The engine is the write-path API.  It keeps the paper's feedback-free
+token pipeline batch-parallel end to end:
 
   * arbitrary-length input is split into a ``(B, MAX_BLOCK + _PAD)`` uint8
     stack and compressed with ONE vmapped+jitted dispatch per micro-batch
@@ -52,7 +51,6 @@ from .jax_compressor import (
     _PAD,
     compress_block_bytes,
     compress_block_records,
-    resolve_candidate_impl,
 )
 from .lz4_types import (
     DEFAULT_HASH_BITS,
@@ -72,21 +70,19 @@ def default_engine() -> "LZ4Engine":
 
 
 @functools.lru_cache(maxsize=None)
-def _batched_compiled(hash_bits, max_match, pws, use_pallas, scan_impl,
-                      candidate_impl, donate, device_emit):
-    """Jitted vmap of the single-block kernel, cached per static config.
+def _batched_compiled(hash_bits, max_match, pws, scan_impl, donate,
+                      device_emit):
+    """Jitted vmap of the single-block graph, cached per static config.
 
-    Module-level cache so every LZ4Engine instance (and the compress_bytes
-    wrapper) shares compilations; jit's own cache then keys on batch shape.
+    Module-level cache so every LZ4Engine instance shares compilations;
+    jit's own cache then keys on batch shape.
     ``device_emit`` selects the fused compress+emit graph (bytes out) over
     the records-only graph (match records out, emitted on host).
     """
     base = compress_block_bytes if device_emit else compress_block_records
     fn = functools.partial(
         base,
-        hash_bits=hash_bits, max_match=max_match, pws=pws,
-        use_pallas=use_pallas, scan_impl=scan_impl,
-        candidate_impl=candidate_impl,
+        hash_bits=hash_bits, max_match=max_match, pws=pws, scan_impl=scan_impl,
     )
     kw = {"donate_argnums": (0,)} if donate else {}
     return jax.jit(jax.vmap(fn), **kw)
@@ -110,7 +106,6 @@ class EngineStats:
     bytes_in: int = 0
     bytes_out: int = 0
     host_bytes: int = 0  # bytes fetched device -> host (records or emit buffers)
-    candidate_impl: str = ""  # the RESOLVED impl that ran ("auto" never runs)
     shards: int = 0  # sharded-fabric calls: shard count of the v4 container
     calls: int = 0  # 1 per finished call (so totals.calls counts calls)
 
@@ -129,8 +124,6 @@ class EngineStats:
             setattr(self, f, getattr(self, f) + getattr(other, f))
         self.calls += max(other.calls, 1)
         self.shards = max(self.shards, other.shards)
-        if other.candidate_impl:
-            self.candidate_impl = other.candidate_impl
 
 
 def _slice_payload(out: np.ndarray, j: int, size: int) -> bytes:
@@ -171,9 +164,7 @@ class LZ4Engine:
                  max_match: int = DEFAULT_MAX_MATCH,
                  pws: int = DEFAULT_PWS,
                  micro_batch: int = 32,
-                 use_pallas: bool = False,
                  scan_impl: str = "sequential",
-                 candidate_impl: str = "auto",
                  donate: bool | None = None,
                  device_emit: bool = True,
                  drain: str = "sliced",
@@ -221,15 +212,7 @@ class LZ4Engine:
         self.max_match = max_match
         self.pws = pws
         self.micro_batch = micro_batch
-        self.use_pallas = use_pallas
         self.scan_impl = scan_impl
-        # "auto" resolves ONCE, here, to the best impl for the active
-        # backend (sortkey on CPU — measured; scatter on GPU and on TPU
-        # without Pallas; fused on TPU with use_pallas) — the dispatch and
-        # the jit cache only ever see a concrete impl name, and
-        # EngineStats.candidate_impl records what actually ran.
-        self.candidate_impl = resolve_candidate_impl(candidate_impl,
-                                                     use_pallas=use_pallas)
         # Donation only pays (and only avoids a warning) off-CPU.
         self.donate = (jax.default_backend() != "cpu") if donate is None else donate
         # device_emit=True: byte emission stays in the jit graph; only the
@@ -283,8 +266,7 @@ class LZ4Engine:
             self._worker = LZ4Engine(
                 hash_bits=self.hash_bits, max_match=self.max_match,
                 pws=self.pws, micro_batch=self.micro_batch,
-                use_pallas=self.use_pallas, scan_impl=self.scan_impl,
-                candidate_impl=self.candidate_impl, donate=self.donate,
+                scan_impl=self.scan_impl, donate=self.donate,
                 device_emit=self.device_emit, drain=self.drain,
                 telemetry=self.telemetry,
             )
@@ -313,13 +295,11 @@ class LZ4Engine:
     def _dispatch(self, stack: np.ndarray, ns: np.ndarray, st: EngineStats):
         """ONE device dispatch for a (M, MAX_BLOCK+_PAD) micro-batch."""
         fn = _batched_compiled(
-            self.hash_bits, self.max_match, self.pws, self.use_pallas,
-            self.scan_impl, self.candidate_impl, self.donate,
-            self.device_emit,
+            self.hash_bits, self.max_match, self.pws, self.scan_impl,
+            self.donate, self.device_emit,
         )
         st.dispatches += 1
-        with self._sp("compress.dispatch", rows=len(ns),
-                      impl=self.candidate_impl):
+        with self._sp("compress.dispatch", rows=len(ns)):
             return fn(jnp.asarray(stack), jnp.asarray(ns))
 
     def _pad_batch(self, chunks: list[bytes]) -> tuple[np.ndarray, np.ndarray]:
@@ -432,7 +412,7 @@ class LZ4Engine:
         ``shards=`` configured the call routes through the sharded fabric
         (distributed/fabric.py) and the output is a frame-v4 container.
         """
-        st = EngineStats(candidate_impl=self.candidate_impl)
+        st = EngineStats()
         self.stats = st
         ob = self._obs_on()
         sp = obs.span_factory(ob)
@@ -484,16 +464,16 @@ class LZ4Engine:
     def compress_to_blocks(self, data: bytes) -> list[bytes]:
         """bytes -> list of raw LZ4 blocks (one per 64 KB, no framing).
 
-        Backwards-compatible output of the old `compress_bytes`: every block
-        is valid LZ4 (no passthrough), lengths must travel out-of-band.
+        Every block is valid LZ4 (no passthrough); lengths must travel
+        out-of-band.
         Sharded engines partition the block stack across shards (same
         contiguous split as `compress`) but the output is the same flat,
         globally-ordered block list.
         """
-        st = EngineStats(candidate_impl=self.candidate_impl)
+        st = EngineStats()
         self.stats = st
         if not data:
-            # Host-emitted empty block: no dispatch, no candidate stage ran.
+            # Host-emitted empty block: no dispatch.
             st.blocks = 1
             self._finish_call(st)
             return [emit_block(b"", [], [], [], [], 0)]

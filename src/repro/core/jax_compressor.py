@@ -2,19 +2,13 @@
 
 This is the TPU-native re-expression of the hardware architecture in Fig. 5:
 
-  Word Shift + Hash Calculation  -> kernels.ops.hash_positions (Pallas/jnp)
+  Word Shift + Hash Calculation  -> kernels.ops.hash_positions
   Hash Table (LVT, multi-port)   -> candidate resolution: because every
         position is written every cycle and reads see previous-cycle
         state, cand(p) = max{q : hash(q)=hash(p), window(q)<window(p)} — a
-        per-bucket predecessor query.  Four bit-identical impls
-        (`candidate_impl`): "sort" (argsort + segment ops), "sortkey"
-        (packed-key sort), "scatter" (scatter-max + log-depth cummax, no
-        sort), and "fused" (the whole hash->LVT->match-extend datapath as
-        ONE Pallas kernel with a VMEM-resident table written/read in
-        window order — kernels/fused_compress.py; jnp twin
-        kernels/ref.fused_ref).  "auto" (the default) resolves per
-        backend (`resolve_candidate_impl`): the measured-fastest impl on
-        CPU, the expected accelerator shapes off-CPU.
+        per-bucket predecessor query, resolved without a sort by
+        scatter-max into a (windows x entries) grid and a log-depth cummax
+        (kernels.ref.scatter_candidates_ref)
   Match Searching                -> vectorized word compare (the table stores
         the 4-byte string; here: words[cand] == words[p])
   Extended Match (bounded, S2)   -> kernels.ops.confirmed_match_lengths: the
@@ -33,9 +27,9 @@ This is the TPU-native re-expression of the hardware architecture in Fig. 5:
         boundary).  The host-side emitters (emitter.py vectorized,
         encoder.py loop-based) survive as the bit-identity oracles.
 
-All variants are bit-identical to the numpy golden model (schemes.py) and to
-each other; tests/test_lz4_jax.py asserts exact equality of the per-window
-match records, tests/test_device_emit.py the emitted bytes.
+Both selects are bit-identical to the numpy golden model (schemes.py);
+tests/test_lz4_jax.py asserts exact equality of the per-window match
+records, tests/test_device_emit.py the emitted bytes.
 """
 from __future__ import annotations
 
@@ -46,12 +40,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from .lz4_types import (
     DEFAULT_HASH_BITS,
     DEFAULT_MAX_MATCH,
     DEFAULT_PWS,
-    LAST_LITERALS,
     MAX_BLOCK,
     MIN_MATCH,
     Sequence,
@@ -59,47 +52,10 @@ from .lz4_types import (
 
 _PAD = 71  # block padding: max max_match (68) + 3 word-shift bytes
 
-# The candidate-resolution implementations selectable via `candidate_impl`
-# (all bit-identical at the match-record level; tests/test_lz4_jax.py,
-# tests/test_fused_compress.py).
-CANDIDATE_IMPLS = ("sort", "sortkey", "scatter", "fused")
-
-
-def resolve_candidate_impl(candidate_impl: str = "auto",
-                           backend: str | None = None,
-                           use_pallas: bool = False) -> str:
-    """Resolve ``"auto"`` to the best impl for a backend.
-
-    On CPU the choice is MEASURED (BENCH_engine_batched.json
-    `candidate_impl`, docs/tuning.md): the packed-key value sort wins
-    (~1.4x over argsort at micro_batch=32 — half the sort payload, no gathers; it also beats
-    scatter's 8 MB grid at every micro-batch on the reference container).
-    Off CPU the choices are the expected accelerator shapes, not yet
-    benchmarked on real hardware: the scatter-max formulation (log-depth
-    cummax, no sort) on GPU and on TPU without Pallas; with
-    ``use_pallas=True`` on TPU, the fused single-pass kernel that keeps
-    the whole datapath in VMEM.  "fused" is only auto-selected when the
-    Pallas kernel would actually run — its jnp twin is the scatter
-    formulation plus extra gathers, so auto-picking it without Pallas
-    would be strictly worse than "scatter".  Concrete impl names pass
-    through unchanged, so callers can always pin one.
-    """
-    if candidate_impl == "auto":
-        backend = backend or jax.default_backend()
-        if backend == "tpu":
-            return "fused" if use_pallas else "scatter"
-        return "scatter" if backend == "gpu" else "sortkey"
-    if candidate_impl not in CANDIDATE_IMPLS:
-        raise ValueError(
-            f"candidate_impl must be 'auto' or one of {CANDIDATE_IMPLS}, "
-            f"got {candidate_impl!r}"
-        )
-    return candidate_impl
-
 # Device-emit output buffer size per block.  The worst case compressed block
 # is literals-only: 1 token + 257 extension bytes + MAX_BLOCK literals =
-# MAX_BLOCK + 258; padded up to a lane-aligned multiple of the emit kernel's
-# tile (2048) so the Pallas path needs no re-padding.
+# MAX_BLOCK + 258; rounded up to MAX_BLOCK + 2048, a multiple of the TPU's
+# 128 lanes and the (rows, OUT_CAP) output shape of every write program.
 OUT_CAP = MAX_BLOCK + 2048
 
 
@@ -113,85 +69,6 @@ class BlockRecords:
     length: jax.Array   # (W,) int32
     offset: jax.Array   # (W,) int32
     size: jax.Array     # () int32 — exact compressed size of the block
-
-
-def _candidates_scatter(hashes, n, hash_bits: int, pws: int):
-    """Scatter-max LVT candidate resolution (beyond-paper optimization).
-
-    cand(p) = max{q : hash(q)=hash(p), win(q)<win(p)} computed WITHOUT the
-    64K-element argsort: scatter-max positions into a (windows x entries)
-    grid (this IS the hash table, materialized over time), exclusive cummax
-    along the window axis (log-depth), then gather at (win(p), hash(p)).
-    Identical output to _candidates; ~2.5x less memory traffic (see
-    EXPERIMENTS.md §Perf).  The formulation itself lives in
-    `kernels.ref.scatter_candidates_ref` — it is also stage 2 of the fused
-    datapath's jnp twin, and sharing one definition keeps the staged impl
-    and the twin from drifting.
-    """
-    from repro.kernels.ref import scatter_candidates_ref
-
-    return scatter_candidates_ref(hashes, n, hash_bits, pws)
-
-
-def _candidates_sortkey(hashes, n, hash_bits: int, pws: int):
-    """Key-packed sort candidate resolution (beyond-paper optimization).
-
-    Because P = 65536 = 2^16, (hash, position) packs into ONE int32 key:
-    `h << 16 | p`.  Sorting values (jnp.sort) instead of argsort halves the
-    sort payload (no index array to permute) and eliminates the two gathers
-    that argsort-based resolution needs; both hash and position are recovered
-    from the sorted key by bit ops.  Bit-identical to _candidates.
-    """
-    P = hashes.shape[0]
-    assert P & (P - 1) == 0, "key packing requires power-of-two P"
-    p = jnp.arange(P, dtype=jnp.int32)
-    valid_pos = p <= n - MIN_MATCH
-    h = jnp.where(valid_pos, hashes, 1 << hash_bits)
-    skey = jnp.sort(h * P + p)
-    h_s = skey >> 16
-    p_s = skey & (P - 1)
-    w_s = p_s // pws
-    prev_h = jnp.concatenate([jnp.full((1,), -1, h_s.dtype), h_s[:-1]])
-    prev_w = jnp.concatenate([jnp.full((1,), -1, w_s.dtype), w_s[:-1]])
-    prev_p = jnp.concatenate([jnp.full((1,), -1, p_s.dtype), p_s[:-1]])
-    same_hash = h_s == prev_h
-    head = ~(same_hash & (w_s == prev_w))
-    group_id = jnp.cumsum(head.astype(jnp.int32)) - 1
-    head_cand = jnp.where(head & same_hash, prev_p, -1)
-    group_val = jnp.zeros((P,), jnp.int32).at[group_id].add(
-        jnp.where(head, head_cand + 1, 0)
-    )
-    cand_s = jnp.take(group_val, group_id) - 1
-    cand = jnp.zeros((P,), jnp.int32).at[p_s].set(cand_s)
-    return cand
-
-
-def _candidates(hashes, n, hash_bits: int, pws: int):
-    """Sort-based LVT candidate resolution. hashes: (P,) int32."""
-    P = hashes.shape[0]
-    p = jnp.arange(P, dtype=jnp.int32)
-    # Positions without a full 4-byte word get a sentinel bucket so they can
-    # neither find nor become candidates.
-    valid_pos = p <= n - MIN_MATCH
-    h = jnp.where(valid_pos, hashes, 1 << hash_bits)
-    key = h * P + p  # unique; sorts by (hash, position)
-    order = jnp.argsort(key).astype(jnp.int32)
-    h_s = jnp.take(h, order)
-    w_s = order // pws
-    prev_h = jnp.concatenate([jnp.full((1,), -1, h_s.dtype), h_s[:-1]])
-    prev_w = jnp.concatenate([jnp.full((1,), -1, w_s.dtype), w_s[:-1]])
-    prev_p = jnp.concatenate([jnp.full((1,), -1, order.dtype), order[:-1]])
-    same_hash = h_s == prev_h
-    head = ~(same_hash & (w_s == prev_w))
-    group_id = jnp.cumsum(head.astype(jnp.int32)) - 1
-    head_cand = jnp.where(head & same_hash, prev_p, -1)
-    # Each group has exactly one head: scatter head candidate, gather back.
-    group_val = jnp.zeros((P,), jnp.int32).at[group_id].add(
-        jnp.where(head, head_cand + 1, 0)
-    )
-    cand_s = jnp.take(group_val, group_id) - 1
-    cand = jnp.zeros((P,), jnp.int32).at[order].set(cand_s)
-    return cand
 
 
 def _select_sequential(valid, lengths, pws: int):
@@ -289,10 +166,7 @@ def _plan_size(emit, pos, length, n):
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=(
-        "hash_bits", "max_match", "pws", "use_pallas", "scan_impl", "candidate_impl",
-    ),
+    jax.jit, static_argnames=("hash_bits", "max_match", "pws", "scan_impl"),
 )
 def compress_block_records(
     block_u8,
@@ -300,9 +174,7 @@ def compress_block_records(
     hash_bits: int = DEFAULT_HASH_BITS,
     max_match: int = DEFAULT_MAX_MATCH,
     pws: int = DEFAULT_PWS,
-    use_pallas: bool = False,
     scan_impl: str = "sequential",
-    candidate_impl: str = "auto",
 ) -> BlockRecords:
     """Compress one padded block; returns per-window match records + size.
 
@@ -310,8 +182,6 @@ def compress_block_records(
     n        : scalar int32 true length (0 <= n <= MAX_BLOCK)
     """
     assert block_u8.shape[0] == MAX_BLOCK + _PAD, block_u8.shape
-    candidate_impl = resolve_candidate_impl(candidate_impl,
-                                            use_pallas=use_pallas)
     block = block_u8.astype(jnp.int32)
     # Zero the padding region so it can never fake matches past n.
     idx = jnp.arange(block.shape[0], dtype=jnp.int32)
@@ -320,33 +190,14 @@ def compress_block_records(
     # Each stage runs under a `jax.named_scope` ("lz4.<stage>"), so the
     # per-operation events of a device trace name the stage they belong to;
     # the scope names are stable (docs/observability.md lists them).
-    if candidate_impl == "fused":
-        # Single-pass datapath: hash, LVT candidate, word compare, and the
-        # bounded extension come back from ONE kernel (or its jnp twin) —
-        # no intermediate hash/word/candidate arrays round-trip through
-        # the graph, and no sort anywhere.
-        with jax.named_scope("lz4.match"):
-            cand, lengths = ops.fused_match_candidates(
-                block, n, positions=MAX_BLOCK, hash_bits=hash_bits, pws=pws,
-                max_match=max_match, use_pallas=use_pallas,
-            )
-            valid = lengths >= MIN_MATCH
-    else:
-        with jax.named_scope("lz4.hash"):
-            _, hashes = ops.hash_positions(block[: MAX_BLOCK + 3], hash_bits,
-                                           use_pallas=use_pallas)
-        cand_fn = {
-            "sort": _candidates,
-            "sortkey": _candidates_sortkey,
-            "scatter": _candidates_scatter,
-        }[candidate_impl]
-        with jax.named_scope("lz4.candidates"):
-            cand = cand_fn(hashes, n, hash_bits, pws)
+    with jax.named_scope("lz4.hash"):
+        _, hashes = ops.hash_positions(block[: MAX_BLOCK + 3], hash_bits)
+    with jax.named_scope("lz4.candidates"):
+        cand = ref.scatter_candidates_ref(hashes, n, hash_bits, pws)
 
-        with jax.named_scope("lz4.extend"):
-            lengths = ops.confirmed_match_lengths(block, cand, n, max_match=max_match,
-                                                  use_pallas=use_pallas)
-            valid = lengths >= MIN_MATCH
+    with jax.named_scope("lz4.extend"):
+        lengths = ops.confirmed_match_lengths(block, cand, n, max_match=max_match)
+        valid = lengths >= MIN_MATCH
 
     with jax.named_scope("lz4.select"):
         if scan_impl == "sequential":
@@ -370,10 +221,7 @@ def compress_block_records(
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "hash_bits", "max_match", "pws", "use_pallas", "scan_impl",
-        "candidate_impl", "out_cap",
-    ),
+    static_argnames=("hash_bits", "max_match", "pws", "scan_impl", "out_cap"),
 )
 def compress_block_bytes(
     block_u8,
@@ -381,9 +229,7 @@ def compress_block_bytes(
     hash_bits: int = DEFAULT_HASH_BITS,
     max_match: int = DEFAULT_MAX_MATCH,
     pws: int = DEFAULT_PWS,
-    use_pallas: bool = False,
     scan_impl: str = "sequential",
-    candidate_impl: str = "auto",
     out_cap: int = OUT_CAP,
 ):
     """Compress one padded block to FINAL BYTES, entirely in-graph.
@@ -401,9 +247,7 @@ def compress_block_bytes(
     """
     rec = compress_block_records(
         block_u8, n,
-        hash_bits=hash_bits, max_match=max_match, pws=pws,
-        use_pallas=use_pallas, scan_impl=scan_impl,
-        candidate_impl=candidate_impl,
+        hash_bits=hash_bits, max_match=max_match, pws=pws, scan_impl=scan_impl,
     )
     with jax.named_scope("lz4.emit"):
         block = block_u8.astype(jnp.int32)
@@ -411,17 +255,14 @@ def compress_block_bytes(
         block = jnp.where(idx < n, block, 0)
         out, total = ops.emit_bytes(
             block, rec.emit, rec.pos, rec.length, rec.offset, n,
-            out_cap=out_cap, use_pallas=use_pallas,
+            out_cap=out_cap,
         )
     return out, total
 
 
 # Batched form for throughput: vmap over a stack of blocks.
 @functools.partial(
-    jax.jit,
-    static_argnames=(
-        "hash_bits", "max_match", "pws", "use_pallas", "scan_impl", "candidate_impl",
-    ),
+    jax.jit, static_argnames=("hash_bits", "max_match", "pws", "scan_impl"),
 )
 def compress_blocks_records(
     blocks_u8,
@@ -429,18 +270,14 @@ def compress_blocks_records(
     hash_bits: int = DEFAULT_HASH_BITS,
     max_match: int = DEFAULT_MAX_MATCH,
     pws: int = DEFAULT_PWS,
-    use_pallas: bool = False,
     scan_impl: str = "sequential",
-    candidate_impl: str = "auto",
 ) -> BlockRecords:
     fn = functools.partial(
         compress_block_records,
         hash_bits=hash_bits,
         max_match=max_match,
         pws=pws,
-        use_pallas=use_pallas,
         scan_impl=scan_impl,
-        candidate_impl=candidate_impl,
     )
     return jax.vmap(fn)(blocks_u8, ns)
 
@@ -464,30 +301,3 @@ def records_to_plan(rec: BlockRecords, n: int) -> list[Sequence]:
         anchor = int(pos[w]) + int(length[w])
     plan.append(Sequence(anchor, n - anchor))
     return plan
-
-
-def compress_bytes(
-    data: bytes,
-    hash_bits: int = DEFAULT_HASH_BITS,
-    max_match: int = DEFAULT_MAX_MATCH,
-    use_pallas: bool = False,
-    scan_impl: str = "sequential",
-) -> list[bytes]:
-    """Deprecated: use :class:`repro.core.engine.LZ4Engine`.
-
-    Thin compatibility wrapper over the batched engine; still returns the
-    historical list-of-raw-LZ4-blocks shape (no frame, no passthrough).
-    """
-    import warnings
-
-    from .engine import LZ4Engine
-
-    warnings.warn(
-        "compress_bytes is deprecated; use LZ4Engine.compress (framed) or "
-        "LZ4Engine.compress_to_blocks", DeprecationWarning, stacklevel=2,
-    )
-    eng = LZ4Engine(
-        hash_bits=hash_bits, max_match=max_match,
-        use_pallas=use_pallas, scan_impl=scan_impl,
-    )
-    return eng.compress_to_blocks(data)

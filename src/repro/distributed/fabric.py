@@ -4,7 +4,7 @@ The paper's throughput story — many parallelization windows compressing
 independently — scales past one chip only if the BLOCK STACK itself is
 sharded.  This module is that refactor: the 64 KB block stack is partitioned
 into contiguous per-shard slices over the mesh axes defined in
-`repro/distributed/sharding.py`, each mesh shard runs the existing fused/auto
+`repro/distributed/sharding.py`, each mesh shard runs the single-device
 datapath (`compress_block_bytes` / `kernels.ops.decode_gather`) on its slice
 inside ONE `shard_map`-wrapped vmapped jit dispatch, and the per-shard
 outputs merge into a **frame v4** container — a shard-aware block table
@@ -122,7 +122,7 @@ def mesh_shard_count(mesh, shard_axes) -> int:
 
 @functools.lru_cache(maxsize=None)
 def _sharded_compress_compiled(mesh, shard_axes, hash_bits, max_match, pws,
-                               use_pallas, scan_impl, candidate_impl):
+                               scan_impl):
     """jit(shard_map(vmap(compress_block_bytes))) cached per static config.
 
     The leading (block) dim of the stack is split over ``shard_axes``; each
@@ -132,9 +132,7 @@ def _sharded_compress_compiled(mesh, shard_axes, hash_bits, max_match, pws,
     """
     fn = functools.partial(
         compress_block_bytes,
-        hash_bits=hash_bits, max_match=max_match, pws=pws,
-        use_pallas=use_pallas, scan_impl=scan_impl,
-        candidate_impl=candidate_impl,
+        hash_bits=hash_bits, max_match=max_match, pws=pws, scan_impl=scan_impl,
     )
     spec = P(shard_axes)
     sm = jax.shard_map(
@@ -174,8 +172,7 @@ def _mesh_collect(engine, chunks, slices, st, sp):
     mb = engine.micro_batch
     fn = _sharded_compress_compiled(
         engine.mesh, tuple(engine.shard_axes), engine.hash_bits,
-        engine.max_match, engine.pws, engine.use_pallas, engine.scan_impl,
-        engine.candidate_impl,
+        engine.max_match, engine.pws, engine.scan_impl,
     )
     to_mesh = NamedSharding(engine.mesh, P(tuple(engine.shard_axes)))
     drained = obs.registry().counter(
@@ -221,8 +218,7 @@ def _mesh_collect(engine, chunks, slices, st, sp):
         with sp("fabric.put", bytes=stack.nbytes, shards=S):
             stack_dev, ns_dev = jax.device_put((stack, ns), to_mesh)
         st.dispatches += 1
-        with sp("compress.dispatch", rows=sum(counts), shards=S,
-                impl=engine.candidate_impl):
+        with sp("compress.dispatch", rows=sum(counts), shards=S):
             res = fn(stack_dev, ns_dev)
         if inflight is not None:
             drain(*inflight)
@@ -340,12 +336,11 @@ def shard_blocks_sharded(engine, data: bytes, st) -> list[bytes]:
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _sharded_decode_compiled(mesh, shard_axes, out_cap, rounds, use_pallas):
+def _sharded_decode_compiled(mesh, shard_axes, out_cap, rounds):
     """jit(shard_map(vmap(decode_gather))) cached per static config."""
     from repro.kernels.ops import decode_gather
 
-    fn = functools.partial(decode_gather, out_cap=out_cap, rounds=rounds,
-                           use_pallas=use_pallas)
+    fn = functools.partial(decode_gather, out_cap=out_cap, rounds=rounds)
     spec = P(shard_axes)
     sm = jax.shard_map(
         jax.vmap(fn), mesh=mesh,
@@ -361,7 +356,7 @@ def _sharded_decode_compiled(mesh, shard_axes, out_cap, rounds, use_pallas):
 
 @functools.lru_cache(maxsize=None)
 def _sharded_plan_decode_compiled(mesh, shard_axes, out_cap, max_lit,
-                                  max_match, rounds, use_pallas):
+                                  max_match, rounds):
     """jit(shard_map(vmap(plan_decode))) cached per static config — the
     speculative-planning twin of `_sharded_decode_compiled`: every shard
     parses, validates, and decodes its raw payload rows in one fused graph
@@ -371,7 +366,7 @@ def _sharded_plan_decode_compiled(mesh, shard_axes, out_cap, max_lit,
 
     fn = functools.partial(plan_decode, out_cap=out_cap, max_lit=max_lit,
                            max_match=max_match, rounds=rounds,
-                           use_pallas=use_pallas, compute_crc=False)
+                           compute_crc=False)
     spec = P(shard_axes)
     sm = jax.shard_map(
         jax.vmap(fn), mesh=mesh,
@@ -491,8 +486,7 @@ def decode_items_sharded(engine, items, st) -> list:
                                                             dp.out_size)
                 rounds = max(rounds, dp.n_waves)
         fn = _sharded_decode_compiled(engine.mesh, tuple(engine.shard_axes),
-                                      caps.out_cap, _round_bucket(rounds),
-                                      engine.use_pallas)
+                                      caps.out_cap, _round_bucket(rounds))
         st.dispatches += 1
         st.device_blocks += sum(counts)
         with sp("decode.execute", rows=sum(counts), shards=S,
@@ -589,7 +583,7 @@ def _decode_items_sharded_spec(engine, items, st) -> list:
     mb = engine.micro_batch
     fn = _sharded_plan_decode_compiled(
         engine.mesh, tuple(engine.shard_axes), caps.out_cap, caps.max_lit,
-        caps.max_match, MAX_RESOLVE_ROUNDS, engine.use_pallas)
+        caps.max_match, MAX_RESOLVE_ROUNDS)
 
     def drain(meta, res):
         start, counts, r = meta

@@ -1,11 +1,10 @@
-"""jit'd wrappers around the Pallas kernels with pure-jnp fallback dispatch.
+"""The device stages' jitted entry points: the jnp graphs the chip runs.
 
-`use_pallas` selects the Pallas path.  Each kernel resolves its execution
-mode from the backend (`backend.interpret_mode`): the interpreter off the
-TPU; on a TPU the compiled Mosaic kernel, or `PallasUnsupportedError` for a
-kernel the TPU compiler refuses (all but fibhash today).  The jnp fallback
-is the oracle in ref.py — both paths are interchangeable and tested for
-exact equality.
+Write side: `hash_positions`, `confirmed_match_lengths` and `emit_bytes`
+(the stages of `core.jax_compressor`'s write graph).  Read side:
+`decode_gather`, the in-graph planner `plan_speculative` / `plan_decode`,
+and `crc32_bytes`.  Their elementwise bodies live in ref.py; the layout
+halves (prefix sums, covering maps, validation, compaction) live here.
 """
 from __future__ import annotations
 
@@ -18,15 +17,6 @@ import numpy as np
 from repro.core.lz4_types import MIN_MATCH
 
 from . import ref
-from .decode_wave import decode_wave_pallas
-from .plan_speculative import plan_spec_pallas
-from .emit_scatter import TILE as EMIT_TILE
-from .emit_scatter import emit_scatter_pallas
-from .fibhash import TILE as HASH_TILE
-from .fibhash import fibhash_pallas
-from .fused_compress import fused_compress_pallas
-from .match_extend import TILE as EXT_TILE
-from .match_extend import match_extend_pallas
 
 
 def _pad_to(x, multiple, value=0):
@@ -37,8 +27,8 @@ def _pad_to(x, multiple, value=0):
     return jnp.concatenate([x, jnp.full((rem,), value, x.dtype)])
 
 
-@functools.partial(jax.jit, static_argnames=("hash_bits", "use_pallas"))
-def hash_positions(block_i32, hash_bits: int = 8, use_pallas: bool = False):
+@functools.partial(jax.jit, static_argnames=("hash_bits",))
+def hash_positions(block_i32, hash_bits: int = 8):
     """Word + Fibonacci hash at every position of a (B,) int32 byte block.
 
     The block must be padded with >= 3 trailing bytes; returns (words, hashes)
@@ -46,81 +36,30 @@ def hash_positions(block_i32, hash_bits: int = 8, use_pallas: bool = False):
     """
     B = block_i32.shape[0]
     P = B - 3
-    b0 = block_i32[:P]
-    b1 = block_i32[1 : P + 1]
-    b2 = block_i32[2 : P + 2]
-    b3 = block_i32[3 : P + 3]
-    if use_pallas:
-        b0p, b1p, b2p, b3p = (_pad_to(b, HASH_TILE) for b in (b0, b1, b2, b3))
-        w, h = fibhash_pallas(b0p, b1p, b2p, b3p, hash_bits=hash_bits)
-        return w[:P], h[:P]
-    return ref.fibhash_ref(b0, b1, b2, b3, hash_bits)
+    return ref.fibhash_ref(block_i32[:P], block_i32[1 : P + 1],
+                           block_i32[2 : P + 2], block_i32[3 : P + 3], hash_bits)
 
 
-@functools.partial(jax.jit, static_argnames=("max_match", "use_pallas"))
-def match_lengths(block_i32, cand, valid, n, max_match: int = 36, use_pallas: bool = False):
-    """Bounded match length per position (0 where ~valid, else in [4, max_match])."""
-    if use_pallas:
-        P = cand.shape[0]
-        candp = _pad_to(cand, EXT_TILE)
-        validp = _pad_to(valid.astype(jnp.bool_), EXT_TILE)
-        need = candp.shape[0] + max_match
-        blk = block_i32
-        if blk.shape[0] < need:
-            blk = jnp.concatenate(
-                [blk, jnp.zeros((need - blk.shape[0],), blk.dtype)]
-            )
-        out = match_extend_pallas(
-            blk, candp, validp, jnp.asarray([n], jnp.int32), max_match=max_match
-        )
-        return out[:P]
+@functools.partial(jax.jit, static_argnames=("max_match",))
+def match_lengths(block_i32, cand, valid, n, max_match: int = 36):
+    """Bounded match length per position (0 where ~valid, else in [4, max_match]).
+
+    The extension at a given confirmed mask (`ref.match_extend_ref`); the
+    write graph confirms for itself (`confirmed_match_lengths`).
+    """
     return ref.match_extend_ref(block_i32, cand, valid, n, max_match)
 
 
-@functools.partial(jax.jit, static_argnames=("max_match", "use_pallas"))
-def confirmed_match_lengths(block_i32, cand, n, max_match: int = 36,
-                            use_pallas: bool = False):
+@functools.partial(jax.jit, static_argnames=("max_match",))
+def confirmed_match_lengths(block_i32, cand, n, max_match: int = 36):
     """The write graph's S2 stage: 4-byte confirm, then bounded extension.
 
     Full match length per position: 0 where no match starts at p (no
     candidate, its first 4 bytes differ, or p > n - MF_LIMIT), else in
-    [4, max_match].  Both paths confirm with `ref.match_words_ref`; the jnp
-    path takes the extension from the same word compare, `use_pallas` from
-    the byte-wise `match_extend` kernel.
+    [4, max_match].  Both come from one word compare, `ref.match_words_ref`.
     """
     start, extra = ref.match_words_ref(block_i32, cand, n, max_match)
-    if use_pallas:
-        return match_lengths(block_i32, cand, start, n, max_match=max_match,
-                             use_pallas=True)
     return jnp.where(start, MIN_MATCH + extra, 0)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("positions", "hash_bits", "pws", "max_match", "use_pallas"),
-)
-def fused_match_candidates(block_i32, n, positions: int, hash_bits: int = 8,
-                           pws: int = 8, max_match: int = 36,
-                           use_pallas: bool = False):
-    """Fused hash -> LVT candidate -> bounded-match datapath (no sort).
-
-    block_i32 : (B,) int32 byte values, zeroed past `n`; B >= positions +
-                max_match (the padded compressor block)
-    n         : scalar int32 true block length
-    positions : static position count P
-
-    Returns ``(cand, lengths)``, both (P,) int32: the LVT candidate per
-    position (-1 where none) and the full bounded match length (0 where no
-    valid match).  `use_pallas` selects the single-pass VMEM-resident
-    kernel (fused_compress.py, grid-sequential LVT) over the whole-block
-    jnp twin (ref.fused_ref); both are elementwise-identical.
-    """
-    if use_pallas:
-        return fused_compress_pallas(
-            block_i32, jnp.asarray(n, jnp.int32)[None], positions,
-            hash_bits=hash_bits, pws=pws, max_match=max_match,
-        )
-    return ref.fused_ref(block_i32, n, positions, hash_bits, pws, max_match)
 
 
 def _ext_len(v):
@@ -132,7 +71,7 @@ def _ext_len(v):
 def _emit_layout(emit, pos, length, offset, n, out_cap: int):
     """Per-sequence output layout + covering-sequence map, all in-graph.
 
-    The XLA half of device-side emission (shared by both `emit_bytes` paths):
+    The layout half of device-side emission (`emit_bytes`):
     log-depth prefix sums turn the per-window match records into exact byte
     offsets — a cummax recovers each sequence's literal anchor (as in
     `_plan_size`), a cumsum over per-sequence byte sizes places every token —
@@ -192,9 +131,8 @@ def _emit_layout(emit, pos, length, offset, n, out_cap: int):
     return seg, fields, total.astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("out_cap", "use_pallas"))
-def emit_bytes(block_i32, emit, pos, length, offset, n, out_cap: int,
-               use_pallas: bool = False):
+@functools.partial(jax.jit, static_argnames=("out_cap",))
+def emit_bytes(block_i32, emit, pos, length, offset, n, out_cap: int):
     """Device-side LZ4 byte emission from per-window match records.
 
     block_i32 : (B,) int32 input byte values, zeroed past `n`
@@ -205,15 +143,11 @@ def emit_bytes(block_i32, emit, pos, length, offset, n, out_cap: int,
 
     Returns ``(out, total)``: a (out_cap,) uint8 buffer whose first `total`
     bytes are the compressed block (bit-identical to
-    `repro.core.emitter.emit_block`, the host oracle) and the exact size.
-    Layout (prefix sums + seg map) is XLA either way; `use_pallas` selects
-    the Pallas byte-materialization kernel over the jnp gather fallback.
+    `repro.core.emitter.emit_block`, the host oracle) and the exact size:
+    the layout (prefix sums + seg map) here, the bytes from
+    `ref.emit_bytes_ref`'s gathers.
     """
     seg, fields, total = _emit_layout(emit, pos, length, offset, n, out_cap)
-    if use_pallas:
-        segp = _pad_to(seg, EMIT_TILE, value=0)
-        out = emit_scatter_pallas(block_i32, segp, fields, total[None])
-        return out[:out_cap].astype(jnp.uint8), total
     return ref.emit_bytes_ref(block_i32, seg, fields, total), total
 
 
@@ -233,17 +167,15 @@ def _span_map(starts, n_valid, out_cap: int):
     return jax.lax.cummax(smap) - 1
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("out_cap", "rounds", "use_pallas"))
+@functools.partial(jax.jit, static_argnames=("out_cap", "rounds"))
 def decode_gather(blk_u8, lit_src, lit_dst, lit_len, match_dst, match_off,
-                  n_lit, n_match, out_size, out_cap: int,
-                  rounds: int, use_pallas: bool = False):
+                  n_lit, n_match, out_size, out_cap: int, rounds: int):
     """Device-side block decode from a fixed-shape `DevicePlan`.
 
     The read-path mirror of `emit_bytes`, same split of labour: the span
-    layout (scatter + cummax covering maps, gathers of per-span fields) is
-    XLA either way; `use_pallas` selects the Pallas pointer-doubling kernel
-    over the jnp fallback for the resolve + byte materialization.
+    layout (scatter + cummax covering maps, gathers of per-span fields)
+    here, the pointer-doubling resolve and byte gather in
+    `ref.decode_gather_ref`.
 
     blk_u8    : (B,) uint8 compressed-payload bytes, zeroed past the true
                 payload length (B is the static payload cap; uint8 so the
@@ -281,12 +213,6 @@ def decode_gather(blk_u8, lit_src, lit_dst, lit_len, match_dst, match_off,
         ptr = jnp.where(is_lit | ~in_range, k, k - moff)
         ptr = jnp.clip(ptr, 0, out_cap - 1)
         lit_blk = jnp.where(is_lit, jnp.take(lit_src, liC) + (k - jnp.take(lit_dst, liC)), 0)
-
-        if use_pallas:
-            out = decode_wave_pallas(blk_i32, lit_blk, ptr,
-                                     jnp.asarray(out_size, jnp.int32)[None],
-                                     rounds=rounds)
-            return out.astype(jnp.uint8)
         return ref.decode_gather_ref(blk_i32, lit_blk, ptr, out_size, rounds)
 
 
@@ -294,7 +220,7 @@ def decode_gather(blk_u8, lit_src, lit_dst, lit_len, match_dst, match_off,
 #
 # Buffer padding past the block cap: the speculative parser's 0xFF-run table
 # is read at index n, so the (B,) buffer must be strictly longer than any
-# payload.  128 keeps B lane-aligned for the Pallas path.
+# payload.  128, one row of TPU lanes, is the planner graphs' buffer shape.
 SPEC_PAD = 128
 
 # Rows of the (SPEC_STATUS,) int32 status vector returned per block.
@@ -306,25 +232,18 @@ SPEC_STATUS = 5
 SPEC_ERR_MISSING_TOKEN = 9
 
 
-def _spec_fields(blk_i32, n, use_pallas: bool):
-    if use_pallas:
-        return plan_spec_pallas(blk_i32, jnp.asarray(n, jnp.int32)[None])
-    return ref.plan_fields_ref(blk_i32, n)
-
-
 @functools.partial(
-    jax.jit, static_argnames=("max_lit", "max_match", "out_cap", "use_pallas"))
+    jax.jit, static_argnames=("max_lit", "max_match", "out_cap"))
 def plan_speculative(blk_u8, n, max_out, max_lit: int = 8448,
-                     max_match: int = 8448, out_cap: int = 65536,
-                     use_pallas: bool = False):
+                     max_match: int = 8448, out_cap: int = 65536):
     """Parse one block's token stream into `DevicePlan` arrays, in-graph.
 
     The device-side replacement for `plan_block_fast` + `to_device_plan`:
-    the speculative kernel (plan_speculative.py / ref.plan_fields_ref)
-    decodes a candidate header at every offset and selects the real chain;
-    this XLA half then validates the chain with the host planner's exact
-    error codes, lays out output offsets with a cumsum, and compacts the
-    headers into fixed-shape plan arrays with one scatter per column.
+    `ref.plan_fields_ref` decodes a candidate header at every offset and
+    selects the real chain; this half then validates the chain with the
+    host planner's exact error codes, lays out output offsets with a
+    cumsum, and compacts the headers into fixed-shape plan arrays with one
+    scatter per column.
 
     blk_u8  : (B,) uint8 payload bytes zeroed past `n`; B > blk_cap
               (pad with `SPEC_PAD`)
@@ -352,8 +271,8 @@ def plan_speculative(blk_u8, n, max_out, max_lit: int = 8448,
     B = blk_u8.shape[0]
     n = jnp.asarray(n, jnp.int32)
     max_out = jnp.asarray(max_out, jnp.int32)
-    is_start, lit_start, lit_len, ls_end, off, mlen, flags = _spec_fields(
-        blk_u8.astype(jnp.int32), n, use_pallas)
+    is_start, lit_start, lit_len, ls_end, off, mlen, flags = ref.plan_fields_ref(
+        blk_u8.astype(jnp.int32), n)
     started = is_start > 0
     trunc_lx = (flags & 1) > 0
     trunc_mx = (flags & 2) > 0
@@ -422,10 +341,9 @@ def plan_speculative(blk_u8, n, max_out, max_lit: int = 8448,
 @functools.partial(
     jax.jit,
     static_argnames=("out_cap", "max_lit", "max_match", "rounds",
-                     "use_pallas", "compute_crc"))
+                     "compute_crc"))
 def plan_decode(blk_u8, n, max_out, out_cap: int, max_lit: int,
-                max_match: int, rounds: int, use_pallas: bool = False,
-                compute_crc: bool = True):
+                max_match: int, rounds: int, compute_crc: bool = True):
     """Fused plan + execute (+ CRC) for one block, entirely in-graph.
 
     Chains `plan_speculative` into `decode_gather` (and `crc32_bytes` when
@@ -443,13 +361,12 @@ def plan_decode(blk_u8, n, max_out, out_cap: int, max_lit: int,
     (lit_src, lit_dst, lit_len, match_dst, match_off, _match_len,
      status) = plan_speculative(
         blk_u8, n, max_out, max_lit=max_lit, max_match=max_match,
-        out_cap=out_cap, use_pallas=use_pallas)
+        out_cap=out_cap)
     ok = (status[SPEC_ERR] == 0) & (status[SPEC_OVERFLOW] == 0)
     out_size = jnp.where(ok, status[SPEC_OUT_SIZE], 0)
     out = decode_gather(blk_u8, lit_src, lit_dst, lit_len, match_dst,
                         match_off, status[SPEC_N_LIT], status[SPEC_N_MATCH],
-                        out_size, out_cap=out_cap, rounds=rounds,
-                        use_pallas=use_pallas)
+                        out_size, out_cap=out_cap, rounds=rounds)
     crc = crc32_bytes(out, out_size) if compute_crc else jnp.uint32(0)
     return out, status, crc
 

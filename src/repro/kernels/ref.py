@@ -1,13 +1,20 @@
-"""Pure-jnp oracles for the Pallas kernels (the correctness references)."""
+"""The jnp bodies of the device stages in ops.py: the code the chip runs.
+
+Each is checked against an independent host oracle in the tests (the NumPy
+golden model, the host emitter, the serial planner and decoder).  Besides
+them, `match_extend_ref` extends at a given confirmed mask; only the tests
+call it (through `ops.match_lengths`), to hold the word-wide extension to
+their byte-at-a-time loop.
+"""
 from __future__ import annotations
 
 import jax.numpy as jnp
 
 from repro.core.lz4_types import HASH_PRIME, MF_LIMIT, MIN_MATCH, LAST_LITERALS
 
-# Row layout of the per-sequence `fields` array consumed by the emit kernels
-# (`emit_bytes_ref` here, `emit_scatter.py` on the Pallas path).  One column
-# per sequence: the W per-window sequences plus the final literals-only one.
+# Row layout of the per-sequence `fields` array consumed by `emit_bytes_ref`.
+# One column per sequence: the W per-window sequences plus the final
+# literals-only one.
 F_START = 0       # output byte offset of the sequence's token
 F_ANCHOR = 1      # input offset of the sequence's first literal
 F_LIT = 2         # literal count
@@ -119,10 +126,9 @@ def scatter_candidates_ref(hashes, n, hash_bits: int, pws: int):
     cand(p) = max{q : hash(q)=hash(p), win(q)<win(p)}: scatter positions
     into a (windows x entries) grid — the hash table materialized over
     time — exclusive cummax along the window axis (log-depth), gather at
-    (win(p), hash(p)).  The single source of this formulation, shared by
-    `fused_ref` below and `jax_compressor._candidates_scatter`
-    (candidate_impl="scatter"), so the twin and the staged impl cannot
-    drift.  Returns (P,) int32, -1 where no candidate/invalid position.
+    (win(p), hash(p)).  The write graph's `lz4.candidates` stage
+    (`jax_compressor.compress_block_records`).  Returns (P,) int32, -1
+    where no candidate/invalid position.
     """
     import jax
 
@@ -139,38 +145,6 @@ def scatter_candidates_ref(hashes, n, hash_bits: int, pws: int):
     excl = jnp.concatenate([jnp.zeros((1, E), jnp.int32), run_max[:-1]], axis=0)
     cand = excl[win, jnp.clip(hashes, 0, E - 1)] - 1
     return jnp.where(valid_pos, cand, -1)
-
-
-def fused_ref(block, n, positions: int, hash_bits: int, pws: int,
-              max_match: int):
-    """jnp twin of the fused compression datapath (fused_compress.py).
-
-    One expression of hash -> LVT candidate -> word compare -> bounded
-    extension, with candidate resolution in the scatter-max formulation
-    (NO sort): scatter positions into a (windows x entries) grid — the
-    hash table materialized over time — exclusive cummax along the window
-    axis, gather at (win(p), hash(p)).  Pinned bit-identical to the
-    `_candidates` sort oracle at the match-record level, and elementwise
-    equal to the Pallas kernel's (cand, lengths) outputs
-    (tests/test_fused_compress.py).
-
-    block     : (B,) int32 byte values, zeroed past `n`; B >= positions +
-                max_match (the padded compressor block)
-    n         : scalar int32 true block length
-    positions : static position count P (P % pws == 0)
-
-    Returns ``(cand, lengths)``: (P,) int32 candidate position (-1 where
-    none/invalid) and full match length (0 where no valid match).
-    """
-    P = positions
-    b0 = block[:P]
-    b1 = block[1 : P + 1]
-    b2 = block[2 : P + 2]
-    b3 = block[3 : P + 3]
-    _, hashes = fibhash_ref(b0, b1, b2, b3, hash_bits)
-    cand = scatter_candidates_ref(hashes, n, hash_bits, pws)
-    start, extra = match_words_ref(block, cand, n, max_match)
-    return cand, jnp.where(start, MIN_MATCH + extra, 0)
 
 
 def emit_bytes_ref(block, seg, fields, total):
@@ -225,7 +199,7 @@ def emit_bytes_ref(block, seg, fields, total):
 
 
 def plan_fields_ref(block, n, chain_rounds: int = 16):
-    """jnp twin of the speculative parse kernel (plan_speculative.py).
+    """The speculative parse of `ops.plan_speculative`.
 
     Decode a CANDIDATE sequence header at EVERY byte offset of a compressed
     block — token nibbles, 0xFF-run literal/match length extensions, the

@@ -284,8 +284,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, verbose: bool = True,
 
 
 def run_lz4_cell(multi_pod: bool, scan_impl: str = "associative",
-                 use_pallas: bool = False, blocks: int = 8192,
-                 hash_bits: int = 8, candidate_impl: str = "sort",
+                 blocks: int = 8192, hash_bits: int = 8,
                  verbose: bool = True) -> dict:
     """Dry-run the paper's own workload: the LZ4 engine over a sharded batch
     of 64 KB blocks (embarrassingly parallel over all mesh axes).
@@ -303,9 +302,7 @@ def run_lz4_cell(multi_pod: bool, scan_impl: str = "associative",
     mesh = make_production_mesh(multi_pod=multi_pod)
     axes = tuple(mesh.axis_names)
     rec = {
-        "arch": "lz4-engine", "shape": f"blocks{blocks}_{scan_impl}"
-        + ("_pallas" if use_pallas else "")
-        + ("_scatter" if candidate_impl == "scatter" else ""),
+        "arch": "lz4-engine", "shape": f"blocks{blocks}_{scan_impl}",
         "mesh": "2x16x16" if multi_pod else "16x16", "chips": mesh.size,
         "mode": "compress", "time": time.time(),
         "bytes_per_step": blocks * MAX_BLOCK,
@@ -318,7 +315,6 @@ def run_lz4_cell(multi_pod: bool, scan_impl: str = "associative",
         def step(bufs, ns):
             out = compress_blocks_records(
                 bufs, ns, hash_bits=hash_bits, scan_impl=scan_impl,
-                use_pallas=use_pallas, candidate_impl=candidate_impl,
             )
             return out.size.astype(jnp.int64).sum(), out.emit.sum()
 

@@ -114,8 +114,6 @@ def cell_lz4_engine():
     results = []
     for label, kw in [
         ("baseline associative", dict(scan_impl="associative")),
-        ("H1: scatter-max candidates", dict(scan_impl="associative", candidate_impl="scatter")),
-        ("H2: key-packed sort", dict(scan_impl="associative", candidate_impl="sortkey")),
         ("hash_bits=12 (4K entries)", dict(scan_impl="associative", hash_bits=12)),
     ]:
         rec = run_lz4_cell(False, verbose=False, **kw)

@@ -4,8 +4,8 @@
     per-byte source maps + pointer doubling + one gather) is bit-identical
     to `execute_plan` on compressor output, overlap-heavy chains, and
     adversarial random plans;
-  * `kernels.ops.decode_gather` (jnp fallback AND Pallas kernel) equals
-    both host oracles for every `rounds` in {exact, worst-case};
+  * `kernels.ops.decode_gather` equals both host oracles for every
+    `rounds` in {exact, worst-case};
   * `LZ4DecodeEngine(executor="device")` decode is bit-identical to
     `decode_frame_serial` on the frame corpora, with `host_bytes` counting
     exactly the decoded payload;
@@ -186,8 +186,7 @@ def test_device_engine_caps_fallback(engine):
     assert de.stats.device_blocks == 0
 
 
-@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
-def test_specplan_caps_fallback(engine, use_pallas):
+def test_specplan_caps_fallback(engine):
     # Same caps-overflow semantics under the SPECULATIVE planner: the
     # in-graph status vector flags the overflow per block, the engine
     # replans that block on host (counted in fallback_blocks), and the
@@ -196,21 +195,19 @@ def test_specplan_caps_fallback(engine, use_pallas):
     data = b"speculative fallback parity " * 20000
     frame = engine.compress(data)
     de = LZ4DecodeEngine(executor="device", plan_on_device=True,
-                         use_pallas=use_pallas,
                          caps=DevicePlanCaps(max_lit=2, max_match=2))
     assert de.decode(frame) == data
     assert de.stats.fallback_blocks == de.stats.blocks
     assert de.stats.device_blocks == 0
     # And with default caps the same engine config takes zero fallbacks.
-    ok = LZ4DecodeEngine(executor="device", plan_on_device=True,
-                         use_pallas=use_pallas)
+    ok = LZ4DecodeEngine(executor="device", plan_on_device=True)
     assert ok.decode(frame) == data
     assert ok.stats.fallback_blocks == 0
     assert ok.stats.device_blocks == ok.stats.blocks
 
 
 # ---------------------------------------------------------------------------
-# decode_gather: jnp fallback AND Pallas kernel vs the oracles
+# decode_gather vs the oracles
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("name", ["text", "zeros", "low_entropy", "one"])
@@ -231,21 +228,10 @@ def test_decode_gather_both_kernels_bit_identical(name):
             jnp.asarray(dp.match_off), jnp.int32(dp.n_lit),
             jnp.int32(dp.n_match), jnp.int32(dp.out_size))
     for rounds in {dp.n_waves, MAX_RESOLVE_ROUNDS}:
-        ref = np.asarray(decode_gather(*args, out_cap=dp.caps.out_cap,
+        out = np.asarray(decode_gather(*args, out_cap=dp.caps.out_cap,
                                        rounds=rounds))
-        pal = np.asarray(decode_gather(*args, out_cap=dp.caps.out_cap,
-                                       rounds=rounds, use_pallas=True))
-        assert ref[: dp.out_size].tobytes() == data, (name, rounds)
-        assert not ref[dp.out_size:].any()
-        assert (ref == pal).all(), (name, rounds)
-
-
-def test_device_engine_pallas_path(engine):
-    data = b"pallas decode parity " * 9000
-    frame = engine.compress(data)
-    de = LZ4DecodeEngine(executor="device", use_pallas=True, micro_batch=2)
-    assert de.decode(frame) == data
-    assert de.stats.device_blocks == de.stats.blocks
+        assert out[: dp.out_size].tobytes() == data, (name, rounds)
+        assert not out[dp.out_size:].any()
 
 
 # ---------------------------------------------------------------------------

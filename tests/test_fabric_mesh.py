@@ -118,8 +118,8 @@ def test_sharded_programs_have_names_of_their_own():
 
     mesh, axes = single_device_mesh(), ("data",)
     assert fabric._sharded_compress_compiled(
-        mesh, axes, 8, 36, 8, False, "sequential", "scatter").__name__ == "fabric_compress"
-    assert fabric._sharded_decode_compiled(mesh, axes, 1024, 2, False).__name__ == \
+        mesh, axes, 8, 36, 8, "sequential").__name__ == "fabric_compress"
+    assert fabric._sharded_decode_compiled(mesh, axes, 1024, 2).__name__ == \
         "fabric_decode"
-    assert fabric._sharded_plan_decode_compiled(mesh, axes, 1024, 64, 64, 2, False).__name__ \
+    assert fabric._sharded_plan_decode_compiled(mesh, axes, 1024, 64, 64, 2).__name__ \
         == "fabric_plan_decode"

@@ -1,4 +1,7 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, exact equality."""
+"""The device stages' jnp paths vs independent oracles: shape/dtype sweeps,
+exact equality.  The hash against a NumPy hash, the match extension against
+a byte-at-a-time loop, the in-graph CRC-32 against `binascii.crc32`."""
+import binascii
 import functools
 
 import numpy as np
@@ -18,38 +21,32 @@ def _np_hash(words: np.ndarray, bits: int) -> np.ndarray:
 @pytest.mark.parametrize("n", [2048, 4096, 65536, 3000, 5555])
 @pytest.mark.parametrize("bits", [6, 8, 12, 13])
 def test_fibhash_pallas_vs_ref(n, bits):
+    """`ops.hash_positions` equals a NumPy word build + Fibonacci hash."""
     rng = np.random.default_rng(n * 31 + bits)
     block = rng.integers(0, 256, n + 3, dtype=np.int32)
-    w_p, h_p = ops.hash_positions(jnp.asarray(block), hash_bits=bits, use_pallas=True)
-    w_r, h_r = ops.hash_positions(jnp.asarray(block), hash_bits=bits, use_pallas=False)
-    np.testing.assert_array_equal(np.asarray(w_p), np.asarray(w_r))
-    np.testing.assert_array_equal(np.asarray(h_p), np.asarray(h_r))
-    # also vs a numpy-computed oracle
+    w, h = ops.hash_positions(jnp.asarray(block), hash_bits=bits)
     d = block.astype(np.uint64)
     words = (d[:n] | (d[1 : n + 1] << 8) | (d[2 : n + 2] << 16) | (d[3 : n + 3] << 24)) & 0xFFFFFFFF
-    np.testing.assert_array_equal(np.asarray(h_p), _np_hash(words, bits))
+    np.testing.assert_array_equal(np.asarray(w).astype(np.uint32), words.astype(np.uint32))
+    np.testing.assert_array_equal(np.asarray(h), _np_hash(words, bits))
 
 
 @pytest.mark.parametrize("n", [1024, 2048, 65536, 2500])
 @pytest.mark.parametrize("max_match", [12, 20, 36, 68])
 def test_match_extend_pallas_vs_ref(n, max_match):
+    """`ops.match_lengths` on random candidates equals the byte loop."""
     rng = np.random.default_rng(n * 7 + max_match)
     # low-entropy data so real matches occur
     block = rng.integers(0, 4, n + max_match, dtype=np.int32)
     cand = rng.integers(0, np.maximum(1, n - 64), n, dtype=np.int32)
     valid = rng.random(n) < 0.5
-    out_p = ops.match_lengths(
-        jnp.asarray(block), jnp.asarray(cand), jnp.asarray(valid), n,
-        max_match=max_match, use_pallas=True,
-    )
-    out_r = ops.match_lengths(
-        jnp.asarray(block), jnp.asarray(cand), jnp.asarray(valid), n,
-        max_match=max_match, use_pallas=False,
-    )
-    np.testing.assert_array_equal(np.asarray(out_p), np.asarray(out_r))
-    assert np.asarray(out_p)[valid].min() >= 4
-    assert np.asarray(out_p).max() <= max_match
-    assert (np.asarray(out_p)[~valid] == 0).all()
+    args = (jnp.asarray(block), jnp.asarray(cand), jnp.asarray(valid), n)
+    out = np.asarray(ops.match_lengths(*args, max_match=max_match))
+    want = np.asarray(_match_extend_bytes(*args, max_match=max_match))
+    np.testing.assert_array_equal(out, want)
+    assert out[valid].min() >= 4
+    assert out.max() <= max_match
+    assert (out[~valid] == 0).all()
 
 
 def test_match_extend_against_python_oracle():
@@ -63,7 +60,7 @@ def test_match_extend_against_python_oracle():
     out = np.asarray(
         ops.match_lengths(
             jnp.asarray(block), jnp.asarray(cand), jnp.asarray(valid), n,
-            max_match=max_match, use_pallas=True,
+            max_match=max_match,
         )
     )
     for p in rng.integers(0, n, 200):
@@ -85,7 +82,7 @@ def test_match_extend_end_of_block_cap():
     out = np.asarray(
         ops.match_lengths(
             jnp.asarray(block), jnp.asarray(cand), jnp.asarray(valid), n,
-            max_match=36, use_pallas=True,
+            max_match=36,
         )
     )
     p = np.arange(n)
@@ -98,7 +95,7 @@ def test_match_extend_end_of_block_cap():
 @functools.partial(jax.jit, static_argnames="max_match")
 def _match_extend_bytes(block, cand, valid, n, max_match):
     """The bounded extension one byte per step: the definition the word-wide
-    `ops.match_lengths` must equal (the device path until word compares)."""
+    `ops.match_lengths` and `ops.confirmed_match_lengths` must equal."""
     P = cand.shape[0]
     p = jnp.arange(P, dtype=jnp.int32)
     max_extra = jnp.clip(n - LAST_LITERALS - (p + MIN_MATCH), 0, max_match - MIN_MATCH)
@@ -209,37 +206,71 @@ def test_match_lengths_words_equal_byte_loop(kind, max_match):
             assert int(got[256 * i + 128]) == min(d, max_match), d
 
 
-# --- execution mode per backend (repro.kernels.backend) ----------------------
+# --- in-graph CRC-32 (the device-side verify) --------------------------------
 
-from repro.kernels import backend  # noqa: E402
-
-
-@pytest.mark.parametrize("kernel", backend.KERNELS)
-def test_interpret_mode_per_backend(kernel):
-    """Interpreted off the TPU; compiled on a TPU, or a named refusal."""
-    assert backend.interpret_mode(kernel, "cpu") is True
-    if kernel in backend.TPU_REFUSED:
-        with pytest.raises(backend.PallasUnsupportedError, match=kernel) as e:
-            backend.interpret_mode(kernel, "tpu")
-        assert backend.TPU_REFUSED[kernel] in str(e.value)
-    else:
-        assert backend.interpret_mode(kernel, "tpu") is False
+def _rng():
+    return np.random.default_rng(20260729)
 
 
-def test_interpret_mode_rejects_unknown_kernel():
-    with pytest.raises(ValueError, match="unknown Pallas kernel"):
-        backend.interpret_mode("no_such_kernel", "tpu")
+def _crc(buf, n):
+    return int(ops.crc32_bytes(jnp.asarray(buf), jnp.int32(n)))
 
 
-def test_use_pallas_on_tpu_raises_for_a_refused_kernel(monkeypatch):
-    """The engine path resolves through the same place: with the backend
-    reported as TPU, tracing the Pallas match-extend raises instead of
-    interpreting."""
-    monkeypatch.setattr(backend.jax, "default_backend", lambda: "tpu")
-    n = 3 * 2048 + 7  # a shape no other test compiles, so this one traces
-    block = jnp.zeros((n + 40,), jnp.int32)
-    cand = jnp.zeros((n,), jnp.int32)
-    valid = jnp.zeros((n,), bool)
-    with pytest.raises(backend.PallasUnsupportedError, match="match_extend"):
-        ops.match_lengths(block, cand, valid, n, max_match=36,
-                          use_pallas=True)
+def _crc_lengths(K, rng):
+    """n at 0, 1, the old corner list, every chunk and piece boundary +-1
+    (above 64 KiB: the first and last four chunk boundaries, those around
+    each piece boundary and 16 drawn at random), K - 1 and K."""
+    chunk, piece = ops._CRC_CHUNK, ops._CRC_PIECE
+    edges = list(range(chunk, K + 1, chunk))
+    if len(edges) > 64:
+        drawn = rng.choice(edges, 16, replace=False).tolist()
+        around = [p + d for p in range(piece, K + 1, piece)
+                  for d in (-chunk, 0, chunk)]
+        edges = edges[:4] + edges[-4:] + drawn + around
+    ns = {0, 1, 3, 7, 8, 9, 15, 16, 70, 255, 256, 257, 1000, K - 1, K}
+    ns |= {e + d for e in edges for d in (-1, 0, 1)}
+    return sorted(n for n in ns if 0 <= n <= K)
+
+
+@pytest.mark.parametrize("K", [1, 7, 8, 9, 1000, 4096, MAX_BLOCK, 1 << 21])
+def test_crc32_bytes_matches_binascii(K):
+    rng = _rng()
+    buf = rng.integers(0, 256, K, np.uint8)
+    lengths = _crc_lengths(K, rng)
+    for n in lengths:
+        assert _crc(buf, n) == binascii.crc32(buf[:n].tobytes()), n
+    # All-0x00 (e.g. 70 zero bytes of a 64 KB row) and all-0xFF data.
+    for fill in (0x00, 0xFF):
+        const = np.full(K, fill, np.uint8)
+        for n in lengths:
+            assert _crc(const, n) == binascii.crc32(const[:n].tobytes()), \
+                (fill, n)
+    # Content past n must not leak into the checksum.
+    cut = min(100, K // 2)
+    buf2 = buf.copy()
+    buf2[cut:] ^= 0xFF
+    assert _crc(buf2, cut) == _crc(buf, cut)
+
+
+def test_crc32_bytes_compiles_once_per_length():
+    """`n` is traced: many lengths share one compiled graph per size."""
+    rng = _rng()
+    for K in (3000, MAX_BLOCK):
+        buf = rng.integers(0, 256, K, np.uint8)
+        _crc(buf, K)
+        graphs = ops.crc32_bytes._cache_size()
+        for n in range(0, K + 1, max(1, K // 17)):
+            assert _crc(buf, n) == binascii.crc32(buf[:n].tobytes()), (K, n)
+        assert ops.crc32_bytes._cache_size() == graphs, K
+
+
+@pytest.mark.parametrize("K", [4096, MAX_BLOCK])
+def test_crc32_bytes_vmapped_rows(K):
+    """The `plan_decode` use: one CRC per row of a vmapped micro-batch."""
+    rng = _rng()
+    rows = rng.integers(0, 256, (6, K), np.uint8)
+    ns = np.array([0, 1, 1023, 1025, K - 1, K], np.int32)
+    got = jax.jit(jax.vmap(ops.crc32_bytes))(jnp.asarray(rows),
+                                             jnp.asarray(ns))
+    want = [binascii.crc32(r[:n].tobytes()) for r, n in zip(rows, ns)]
+    assert [int(g) for g in got] == want

@@ -2,8 +2,8 @@
 
 Two flavours: hypothesis-driven generative tests (skipped individually when
 hypothesis is not installed in the image) and seeded differential sweeps
-(always run) pinning every datapath variant — candidate impl x shard count x
-drain mode — to byte-identical frames.
+(always run) pinning every datapath variant — shard count x drain mode — to
+byte-identical frames.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -107,16 +107,14 @@ def test_scheme_ratio_ordering(data):
 
 
 # ---------------------------------------------------------------------------
-# Differential fabric tests: frame bytes must be IDENTICAL across candidate
-# impls x shard counts x drain modes (the sharded fabric's merge stage and
-# every datapath variant are pinned to one another, not just to "decodes
-# back").  Seeded adversarial corpora, not hypothesis: each engine config
+# Differential fabric tests: frame bytes must be IDENTICAL across shard
+# counts x drain modes (the sharded fabric's merge stage and every datapath
+# variant are pinned to one another, not just to "decodes back").  Seeded adversarial corpora, not hypothesis: each engine config
 # costs a jit compile, so the sweep is deterministic and shared.
 # ---------------------------------------------------------------------------
 
 from repro.core import LZ4Engine  # noqa: E402
 from repro.core.frame import decode_frame_serial, frame_info  # noqa: E402
-from repro.core.jax_compressor import CANDIDATE_IMPLS  # noqa: E402
 from repro.core.lz4_types import MAX_BLOCK  # noqa: E402
 
 _SHARD_COUNTS = (1, 2, 4)
@@ -153,21 +151,18 @@ def _payload_bytes(frame: bytes) -> list[bytes]:
 @pytest.mark.parametrize("seed", [0, 1])
 def test_frame_identity_impl_x_shards_x_drain(seed):
     data = _adversarial_corpus(seed)
-    reference = {}  # shards -> frame from the first (impl, drain) combo
+    reference = {}  # shards -> frame from the first drain mode
     ref_payloads = None
     for shards in _SHARD_COUNTS:
-        for impl in CANDIDATE_IMPLS:
-            for drain in _DRAINS:
-                eng = LZ4Engine(candidate_impl=impl, drain=drain,
-                                shards=shards)
-                frame = eng.compress(data)
-                # identity across impls and drains (fixed shard count)
-                if shards not in reference:
-                    reference[shards] = frame
-                    assert decode_frame_serial(frame) == data
-                else:
-                    assert frame == reference[shards], \
-                        f"impl={impl} drain={drain} shards={shards}"
+        for drain in _DRAINS:
+            frame = LZ4Engine(drain=drain, shards=shards).compress(data)
+            # identity across drains (fixed shard count)
+            if shards not in reference:
+                reference[shards] = frame
+                assert decode_frame_serial(frame) == data
+            else:
+                assert frame == reference[shards], \
+                    f"drain={drain} shards={shards}"
         # across shard counts the container header differs (v4 shard
         # column) but every block's payload bytes must be identical
         payloads = _payload_bytes(reference[shards])

@@ -1,10 +1,10 @@
 """Speculative in-graph decode planning (PR-9 acceptance surface).
 
-The speculative planner (`kernels.plan_speculative` Pallas kernel +
-`kernels.ref.plan_fields_ref` jnp twin, validated/compacted by
-`kernels.ops.plan_speculative`) decodes a CANDIDATE sequence header at
-every byte offset and chain-selects the one real parse — replacing the
-host `plan_block_fast` O(n) walk on the device decode path.  Pinned here:
+The speculative planner (`kernels.ref.plan_fields_ref`, validated and
+compacted by `kernels.ops.plan_speculative`) decodes a CANDIDATE sequence
+header at every byte offset and chain-selects the one real parse —
+replacing the host `plan_block_fast` O(n) walk on the device decode path.
+Pinned here:
 
   * plan bit-identity: the compacted device plan (literal/match columns,
     counts, out_size) equals `to_device_plan(plan_block(...))` — the
@@ -13,8 +13,6 @@ host `plan_block_fast` O(n) walk on the device decode path.  Pinned here:
     hand-built token streams;
   * rejection identity: truncated and lying streams fail with the SAME
     error message the host planner raises, position-priority included;
-  * kernel twin identity: the Pallas kernel's raw field arrays equal the
-    jnp reference bit for bit;
   * the fused `plan_decode` graph (plan + gather + CRC in one dispatch)
     reproduces payload bytes and `block_crc`;
   * `LZ4DecodeEngine(executor="device", plan_on_device=True)` decodes
@@ -108,7 +106,7 @@ def _lying_corpus() -> dict[str, tuple[bytes, int]]:
     }
 
 
-def _spec_plan(blk: bytes, max_out: int = MAX_BLOCK, use_pallas=False):
+def _spec_plan(blk: bytes, max_out: int = MAX_BLOCK):
     import jax.numpy as jnp
 
     from repro.kernels import ops as kops
@@ -119,15 +117,14 @@ def _spec_plan(blk: bytes, max_out: int = MAX_BLOCK, use_pallas=False):
                                 jnp.int32(max_out),
                                 max_lit=_CAPS.max_lit,
                                 max_match=_CAPS.max_match,
-                                out_cap=_CAPS.out_cap,
-                                use_pallas=use_pallas)
+                                out_cap=_CAPS.out_cap)
     return [np.asarray(a) for a in res]
 
 
-def _assert_plan_matches_oracle(name, blk, use_pallas):
+def _assert_plan_matches_oracle(name, blk):
     from repro.kernels import ops as kops
 
-    *cols, status = _spec_plan(blk, use_pallas=use_pallas)
+    *cols, status = _spec_plan(blk)
     lit_src, lit_dst, lit_len, match_dst, match_off, match_len = cols
     assert status[kops.SPEC_ERR] == 0, (name, status)
     assert status[kops.SPEC_OVERFLOW] == 0, name
@@ -149,22 +146,18 @@ def _assert_plan_matches_oracle(name, blk, use_pallas):
 # Plan bit-identity vs the serial oracle
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("use_pallas", [False, True],
-                         ids=["jnp", "pallas"])
-def test_speculative_plan_equals_serial_oracle(use_pallas):
+def test_speculative_plan_equals_serial_oracle():
     for name, blk in _adversarial_corpus().items():
-        _assert_plan_matches_oracle(name, blk, use_pallas)
+        _assert_plan_matches_oracle(name, blk)
 
 
-@pytest.mark.parametrize("use_pallas", [False, True],
-                         ids=["jnp", "pallas"])
-def test_speculative_rejects_identically(use_pallas):
+def test_speculative_rejects_identically():
     from repro.kernels import ops as kops
 
     for name, (blk, max_out) in _lying_corpus().items():
         with pytest.raises(LZ4FormatError) as ei:
             plan_block_fast(blk, max_out=max_out)
-        *_, status = _spec_plan(blk, max_out=max_out, use_pallas=use_pallas)
+        *_, status = _spec_plan(blk, max_out=max_out)
         err = int(status[kops.SPEC_ERR])
         assert err != 0, name
         assert _spec_err_message(err) == str(ei.value), name
@@ -189,7 +182,7 @@ def test_truncation_sweep_rejects_identically(name):
         err = int(status[kops.SPEC_ERR])
         if oracle_msg is None:
             assert err == 0, (name, cut)
-            _assert_plan_matches_oracle(f"{name}[: {cut}]", t, False)
+            _assert_plan_matches_oracle(f"{name}[: {cut}]", t)
         else:
             assert err != 0, (name, cut, oracle_msg)
             assert _spec_err_message(err) == oracle_msg, (name, cut)
@@ -219,38 +212,16 @@ def test_interior_flip_sweep_rejects_identically():
             if status[kops.SPEC_OVERFLOW]:
                 continue  # legal parse that exceeds caps: host fallback
             assert err == 0, pos
-            _assert_plan_matches_oracle(f"flip@{pos}", m, False)
+            _assert_plan_matches_oracle(f"flip@{pos}", m)
         else:
             assert err != 0 and _spec_err_message(err) == oracle_msg, pos
 
 
 # ---------------------------------------------------------------------------
-# Kernel twin identity + the fused plan_decode graph
+# The fused plan_decode graph
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("name", ["text", "zeros", "rle_529", "lit_525",
-                                  "one"])
-def test_pallas_kernel_equals_jnp_twin(name):
-    import jax.numpy as jnp
-
-    from repro.kernels import ref
-    from repro.kernels.plan_speculative import plan_spec_pallas
-
-    blk = _adversarial_corpus()[name]
-    B = _CAPS.blk_cap + 128
-    buf = np.zeros(B, np.int32)
-    buf[: len(blk)] = np.frombuffer(blk, np.uint8)
-    block = jnp.asarray(buf)
-    want = ref.plan_fields_ref(block, jnp.int32(len(blk)))
-    got = plan_spec_pallas(block, jnp.asarray([len(blk)], jnp.int32))
-    for w, g, field in zip(want, got, ("is_start", "lit_start", "lit_len",
-                                       "ls_end", "off", "mlen", "flags")):
-        assert np.array_equal(np.asarray(w), np.asarray(g)), (name, field)
-
-
-@pytest.mark.parametrize("use_pallas", [False, True],
-                         ids=["jnp", "pallas"])
-def test_fused_plan_decode_payload_and_crc(use_pallas):
+def test_fused_plan_decode_payload_and_crc():
     import jax.numpy as jnp
 
     from repro.core import block_crc
@@ -267,8 +238,7 @@ def test_fused_plan_decode_payload_and_crc(use_pallas):
         out, status, crc = plan_decode(
             jnp.asarray(buf), jnp.int32(len(blk)), jnp.int32(MAX_BLOCK),
             out_cap=_CAPS.out_cap, max_lit=_CAPS.max_lit,
-            max_match=_CAPS.max_match, rounds=MAX_RESOLVE_ROUNDS,
-            use_pallas=use_pallas)
+            max_match=_CAPS.max_match, rounds=MAX_RESOLVE_ROUNDS)
         status = np.asarray(status)
         assert status[kops.SPEC_ERR] == 0, name
         size = int(status[kops.SPEC_OUT_SIZE])
@@ -326,15 +296,6 @@ def test_specplan_engine_bit_identical(engine, spec_engine):
         got = spec_engine.decode(frame)
         assert got == data, name
         assert got == decode_frame_serial(frame), name
-
-
-def test_specplan_engine_pallas_variant(engine):
-    de = LZ4DecodeEngine(executor="device", plan_on_device=True,
-                         use_pallas=True, micro_batch=2)
-    data = b"pallas speculative parity " * 9000
-    frame = engine.compress(data)
-    assert de.decode(frame) == data
-    assert de.stats.device_blocks == de.stats.blocks
 
 
 def test_specplan_no_host_planner_calls(engine, monkeypatch):
@@ -460,21 +421,15 @@ _MESH_SUBPROC = textwrap.dedent("""
     data = (b"sharded speculative planning " * 5000
             + rng.integers(0, 256, 30000, np.uint8).tobytes())
     frame = LZ4Engine(micro_batch=4, shards=3).compress(data)
-    results = {}
-    for up in (False, True):
-        mesh = make_mesh((2, 2), ("data", "model"))
-        dec = LZ4DecodeEngine(mesh=mesh, executor="device",
-                              plan_on_device=True, micro_batch=2,
-                              use_pallas=up)
-        assert dec.decode(frame) == data, up
-        st = dec.stats
-        assert st.fallback_blocks == 0, st
-        assert st.device_blocks == st.blocks - st.raw_blocks, st
-        results["pallas" if up else "jnp"] = {
-            "dispatches": st.dispatches,
-            "device_blocks": st.device_blocks,
-        }
-    print("RESULT:" + json.dumps({"ok": True, "meshes": results}))
+    mesh = make_mesh((2, 2), ("data", "model"))
+    dec = LZ4DecodeEngine(mesh=mesh, executor="device",
+                          plan_on_device=True, micro_batch=2)
+    assert dec.decode(frame) == data
+    st = dec.stats
+    assert st.fallback_blocks == 0, st
+    assert st.device_blocks == st.blocks - st.raw_blocks, st
+    print("RESULT:" + json.dumps({"ok": True, "dispatches": st.dispatches,
+                                  "device_blocks": st.device_blocks}))
 """)
 
 
@@ -491,5 +446,4 @@ def test_subprocess_mesh_specplan():
             if l.startswith("RESULT:")][0]
     result = json.loads(line[len("RESULT:"):])
     assert result["ok"]
-    for leg in ("jnp", "pallas"):
-        assert result["meshes"][leg]["device_blocks"] > 0
+    assert result["device_blocks"] > 0

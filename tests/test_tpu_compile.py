@@ -9,9 +9,7 @@ here instead of on the chip:
   * the engines' default graphs: the vmapped write graph at micro_batch 32,
     the device decode graphs (`decode_gather`, `plan_decode`) at
     micro_batch 8, and the in-graph CRC-32 of one decoded row;
-  * the Pallas kernels with ``interpret=False``: every kernel the resolver
-    (`repro.kernels.backend`) lets run on a TPU compiles to a Mosaic custom
-    call, and every kernel it refuses is still refused by the compiler.
+  * the sharded fabric's write graph over a four-chip mesh.
 
 The topology is described inside a module fixture, never at import, and the
 persistent compilation cache is off while these compiles run.
@@ -29,13 +27,7 @@ from repro.core.decode_plan import MAX_RESOLVE_ROUNDS, DevicePlanCaps
 from repro.core.engine import _batched_compiled
 from repro.core.jax_compressor import _PAD, OUT_CAP
 from repro.core.lz4_types import MAX_BLOCK
-from repro.kernels import backend, ops, ref
-from repro.kernels.decode_wave import decode_wave_pallas
-from repro.kernels.emit_scatter import emit_scatter_pallas
-from repro.kernels.fibhash import fibhash_pallas
-from repro.kernels.fused_compress import fused_compress_pallas
-from repro.kernels.match_extend import match_extend_pallas
-from repro.kernels.plan_speculative import plan_spec_pallas
+from repro.kernels import ops
 
 HASH_BITS, MAX_MATCH, PWS = 8, 36, 8
 CAPS = DevicePlanCaps()
@@ -69,15 +61,10 @@ def _spec(sharding, shape, dtype=jnp.int32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
-    return jax.jit(fn).lower(*args).compile()
-
-
 def test_default_write_graph_compiles(one_chip):
     """The engine's dispatch on a TPU: scatter candidates, sequential select,
     device emit, donated input, 32 blocks per micro-batch."""
-    fn = _batched_compiled(HASH_BITS, MAX_MATCH, PWS, False, "sequential",
-                           "scatter", True, True)
+    fn = _batched_compiled(HASH_BITS, MAX_MATCH, PWS, "sequential", True, True)
     c = fn.lower(_spec(one_chip, (32, MAX_BLOCK + _PAD), jnp.uint8),
                  _spec(one_chip, (32,))).compile()
     out, size = c.out_info
@@ -88,7 +75,7 @@ def test_default_write_graph_compiles(one_chip):
 def test_device_decode_graph_compiles(one_chip, graph):
     m = 8
     if graph == "decode_gather":
-        fn = _device_decode_compiled(CAPS.out_cap, MAX_RESOLVE_ROUNDS, False)
+        fn = _device_decode_compiled(CAPS.out_cap, MAX_RESOLVE_ROUNDS)
         args = ([_spec(one_chip, (m, CAPS.blk_cap), jnp.uint8)]
                 + [_spec(one_chip, (m, CAPS.max_lit))] * 3
                 + [_spec(one_chip, (m, CAPS.max_match))] * 2
@@ -96,7 +83,7 @@ def test_device_decode_graph_compiles(one_chip, graph):
     else:
         fn = _device_plan_decode_compiled(CAPS.out_cap, CAPS.max_lit,
                                           CAPS.max_match, MAX_RESOLVE_ROUNDS,
-                                          False, True)
+                                          True)
         args = [_spec(one_chip, (m, CAPS.blk_cap + ops.SPEC_PAD), jnp.uint8),
                 _spec(one_chip, (m,)), _spec(one_chip, (m,))]
     c = fn.lower(*args).compile()
@@ -121,8 +108,7 @@ def test_fabric_write_graph_compiles_on_four_chips(topo, one_chip):
 
     mesh = Mesh(topo.devices[:4], ("data",))
     fn = fabric._sharded_compress_compiled(mesh, ("data",), HASH_BITS,
-                                           MAX_MATCH, PWS, False,
-                                           "sequential", "scatter")
+                                           MAX_MATCH, PWS, "sequential")
     rows = NamedSharding(mesh, PartitionSpec("data"))
     c = fn.lower(_spec(rows, (4 * 32, MAX_BLOCK + _PAD), jnp.uint8),
                  _spec(rows, (4 * 32,))).compile()
@@ -131,57 +117,3 @@ def test_fabric_write_graph_compiles_on_four_chips(topo, one_chip):
     text = c.as_text()
     assert "all-gather" not in text and "all-reduce" not in text
 
-
-B = MAX_BLOCK + _PAD
-# (kernel, builder of (fn, arg shapes)) at the sizes ops.py calls them with.
-KERNEL_CALLS = {
-    "fibhash": lambda s: (
-        lambda a, b, c, d: fibhash_pallas(a, b, c, d, hash_bits=HASH_BITS,
-                                          interpret=False),
-        [_spec(s, (MAX_BLOCK,))] * 4),
-    "match_extend": lambda s: (
-        lambda blk, cand, valid, n: match_extend_pallas(
-            blk, cand, valid, n, max_match=MAX_MATCH, interpret=False),
-        [_spec(s, (B,)), _spec(s, (MAX_BLOCK,)),
-         _spec(s, (MAX_BLOCK,), jnp.bool_), _spec(s, (1,))]),
-    "fused_compress": lambda s: (
-        lambda blk, n: fused_compress_pallas(
-            blk, n, MAX_BLOCK, hash_bits=HASH_BITS, pws=PWS,
-            max_match=MAX_MATCH, interpret=False),
-        [_spec(s, (B,)), _spec(s, (1,))]),
-    "emit_scatter": lambda s: (
-        lambda blk, seg, fields, total: emit_scatter_pallas(
-            blk, seg, fields, total, interpret=False),
-        [_spec(s, (B,)), _spec(s, (OUT_CAP,)),
-         _spec(s, (ref.N_FIELDS, MAX_BLOCK // PWS + 1)), _spec(s, (1,))]),
-    "decode_wave": lambda s: (
-        lambda blk, lit, ptr, total: decode_wave_pallas(
-            blk, lit, ptr, total, rounds=MAX_RESOLVE_ROUNDS, interpret=False),
-        [_spec(s, (CAPS.blk_cap,)), _spec(s, (CAPS.out_cap,)),
-         _spec(s, (CAPS.out_cap,)), _spec(s, (1,))]),
-    "plan_spec": lambda s: (
-        lambda blk, n: plan_spec_pallas(blk, n, interpret=False),
-        [_spec(s, (CAPS.blk_cap + ops.SPEC_PAD,)), _spec(s, (1,))]),
-}
-
-
-def test_kernel_calls_cover_every_kernel():
-    assert set(KERNEL_CALLS) == set(backend.KERNELS)
-
-
-@pytest.mark.parametrize(
-    "kernel", [k for k in backend.KERNELS if k not in backend.TPU_REFUSED])
-def test_kernel_compiles_for_tpu(one_chip, kernel):
-    fn, args = KERNEL_CALLS[kernel](one_chip)
-    assert "tpu_custom_call" in _compile(fn, *args).as_text()
-
-
-@pytest.mark.parametrize("kernel", sorted(backend.TPU_REFUSED))
-def test_refused_kernel_is_still_refused(one_chip, kernel):
-    """If one of these starts to compile, drop it from TPU_REFUSED."""
-    fn, args = KERNEL_CALLS[kernel](one_chip)
-    reason = backend.TPU_REFUSED[kernel]
-    with pytest.raises(Exception) as e:
-        _compile(fn, *args)
-    first = "dynamic_slice" if "dynamic_slice" in reason else "Only 2D gather"
-    assert first in str(e.value)
