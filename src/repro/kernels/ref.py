@@ -167,20 +167,19 @@ def emit_bytes_ref(block, seg, fields, total):
     fields : (N_FIELDS, S) int32 per-sequence layout (see F_* rows above)
     total  : scalar int32 exact compressed size; positions >= total emit 0
 
+    Two gathers: one column gather of the whole `fields` table at `seg`,
+    whose N_FIELDS = 8 rows (in F_* order) fill a TPU (8, 128) tile, so it
+    does not pad and costs about what one 1-D gather costs (GATHER_RUNGS is
+    8 for the same reason; a ninth row would pad the tile to 16); and the
+    literal gather from the block, whose index differs per position.
+
     Returns (K,) uint8.  Bit-identical to `repro.core.emitter.emit_block`
     (asserted in tests/test_device_emit.py) — purely elementwise once the
     per-sequence fields are gathered, which is what makes it a kernel shape.
     """
     K = seg.shape[0]
     k = jnp.arange(K, dtype=jnp.int32)
-    st = jnp.take(fields[F_START], seg)
-    anc = jnp.take(fields[F_ANCHOR], seg)
-    lit = jnp.take(fields[F_LIT], seg)
-    le = jnp.take(fields[F_LIT_EXT], seg)
-    mlx = jnp.take(fields[F_MLX], seg)
-    me = jnp.take(fields[F_MATCH_EXT], seg)
-    off = jnp.take(fields[F_OFF], seg)
-    hm = jnp.take(fields[F_HAS_MATCH], seg)
+    st, anc, lit, le, mlx, me, off, hm = jnp.take(fields, seg, axis=1)
 
     r = k - st
     token = (jnp.minimum(lit, 15) << 4) | jnp.where(hm > 0, jnp.minimum(mlx, 15), 0)

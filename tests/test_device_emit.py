@@ -6,6 +6,8 @@ Covers the PR-3 acceptance surface:
     adversarial corpora — incompressible, all-zero, RLE runs that end at
     token-nibble and extension-byte boundaries;
   * the in-graph size equals `BlockRecords.size` and never exceeds OUT_CAP;
+  * `emit_bytes` vmapped over blocks, as the write program runs it, on
+    hand-built records that take every per-sequence field to its edges;
   * `LZ4Engine(device_emit=True)` frames are bit-identical to
     `device_emit=False` frames, which in turn are guarded against drift
     from the seed construction (emit_block + encode_frame by hand);
@@ -24,7 +26,7 @@ from repro.core.jax_compressor import (
     compress_block_records,
     pad_block,
 )
-from repro.core.lz4_types import MAX_BLOCK
+from repro.core.lz4_types import MAX_BLOCK, MF_LIMIT
 
 
 def _rng():
@@ -95,6 +97,88 @@ def test_device_emit_random_lengths():
         data = bytes(rng.integers(0, 8, size, np.uint8))
         _, oracle, out, s = _oracle_and_device(data)
         assert out[:s].tobytes() == oracle, size
+
+
+# ---------------------------------------------------------------------------
+# emit_bytes under vmap on hand-built records: every field at its edges
+# ---------------------------------------------------------------------------
+
+# A block is a list of pieces, ("lit", count) or ("match", length, offset);
+# its n is the bytes they cover.  Each match starts in a window of its own.
+_EDGE_CASES = {
+    "lit_14": [("lit", 14), ("match", 18, 14), ("lit", 8)],
+    "lit_15": [("lit", 15), ("match", 19, 15), ("lit", 8)],
+    "lit_270": [("lit", 270), ("match", 36, 100), ("lit", 8)],
+    "lit_271": [("lit", 271), ("match", 36, 271), ("lit", 8)],
+    "whole_block_literals": [("lit", MAX_BLOCK)],
+    "mlen_18_19_36": [("lit", 20), ("match", 18, 20), ("lit", 3),
+                      ("match", 19, 5), ("match", 36, 30), ("lit", 10)],
+    "offset_1": [("lit", 1), ("match", 36, 1), ("match", 19, 1), ("lit", 7)],
+    "offset_65500": [("lit", 65500), ("match", 19, 65500), ("lit", 17)],
+    # 0xFFFF fills both offset bytes; no 64 KiB block can reach that far
+    # back and still end a match 5 bytes before its end, so this one is
+    # compared to the oracle but not decoded.
+    "offset_65535": [("lit", 40), ("match", 36, 65535), ("lit", 12)],
+    "final_literals_only": [("lit", 100)],
+    "n_0": [],
+    "n_mf_limit": [("lit", MF_LIMIT)],
+}
+
+
+def _edge_block(pieces, seed):
+    """(data, emit, pos, length, offset) for one hand-built block, with the
+    write graph's record shape: one record per 8-position window."""
+    rng = np.random.default_rng(seed)
+    W = MAX_BLOCK // 8
+    emit = np.zeros(W, bool)
+    pos = np.full(W, -1, np.int32)
+    length = np.zeros(W, np.int32)
+    offset = np.zeros(W, np.int32)
+    out = bytearray()
+    for kind, *args in pieces:
+        if kind == "lit":
+            out += rng.integers(0, 256, args[0], np.uint8).tobytes()
+            continue
+        mlen, moff = args
+        p = len(out)
+        w = p // 8
+        assert not emit[w:].any()
+        emit[w], pos[w], length[w], offset[w] = True, p, mlen, moff
+        for i in range(mlen):
+            src = p + i - moff
+            out.append(out[src] if src >= 0 else int(rng.integers(0, 256)))
+    return bytes(out), emit, pos, length, offset
+
+
+@pytest.mark.parametrize("name", list(_EDGE_CASES))
+def test_emit_bytes_vmapped_field_edges(name):
+    """`emit_bytes` vmapped over 3 blocks, the write program's form, against
+    `emit_block` row by row; the batch is the named case and the two after
+    it, so every case also runs beside other blocks."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.jax_compressor import _PAD
+    from repro.kernels import ops
+
+    names = list(_EDGE_CASES)
+    i = names.index(name)
+    rows = [names[(i + j) % len(names)] for j in range(3)]
+    blocks = [_edge_block(_EDGE_CASES[r], 20260731 + names.index(r)) for r in rows]
+    bufs = np.zeros((3, MAX_BLOCK + _PAD), np.int32)
+    for b, (data, *_) in zip(bufs, blocks):
+        b[: len(data)] = np.frombuffer(data, np.uint8)
+    recs = [jnp.asarray(np.stack([blk[f] for blk in blocks])) for f in range(1, 5)]
+    ns = jnp.asarray([len(blk[0]) for blk in blocks], jnp.int32)
+    emit_rows = jax.vmap(lambda *a: ops.emit_bytes(*a, out_cap=OUT_CAP))
+    out, size = emit_rows(jnp.asarray(bufs), *recs, ns)
+    out, size = np.asarray(out), np.asarray(size)
+    for r, (data, emit, pos, length, offset), o, s in zip(rows, blocks, out, size):
+        oracle = emit_block(data, emit, pos, length, offset, len(data))
+        assert o[:s].tobytes() == oracle, r
+        assert np.all(o[s:] == 0), r
+        if r != "offset_65535":
+            assert decode_block(o[:s].tobytes(), max_out=len(data)) == data, r
 
 
 # ---------------------------------------------------------------------------

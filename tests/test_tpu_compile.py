@@ -9,12 +9,15 @@ here instead of on the chip:
   * the engines' default graphs: the vmapped write graph at micro_batch 32,
     the device decode graphs (`decode_gather`, `plan_decode`) at
     micro_batch 8, and the in-graph CRC-32 of one decoded row;
-  * the sharded fabric's write graph over a four-chip mesh.
+  * the sharded fabric's write graph over a four-chip mesh;
+  * the write graph's emit stage keeps to two gathers (its field table in
+    one column gather, and the literal bytes).
 
 The topology is described inside a module fixture, never at import, and the
 persistent compilation cache is off while these compiles run.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -61,14 +64,39 @@ def _spec(sharding, shape, dtype=jnp.int32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_default_write_graph_compiles(one_chip):
+@pytest.fixture(scope="module")
+def write_program(one_chip):
     """The engine's dispatch on a TPU: scatter candidates, sequential select,
     device emit, donated input, 32 blocks per micro-batch."""
     fn = _batched_compiled(HASH_BITS, MAX_MATCH, PWS, "sequential", True, True)
-    c = fn.lower(_spec(one_chip, (32, MAX_BLOCK + _PAD), jnp.uint8),
-                 _spec(one_chip, (32,))).compile()
-    out, size = c.out_info
+    return fn.lower(_spec(one_chip, (32, MAX_BLOCK + _PAD), jnp.uint8),
+                    _spec(one_chip, (32,))).compile()
+
+
+def test_default_write_graph_compiles(write_program):
+    out, size = write_program.out_info
     assert out.shape == (32, OUT_CAP) and size.shape == (32,)
+
+
+def test_emit_stage_gathers_field_table_once(write_program):
+    """The emit stage gathers twice: the 8-row per-sequence field table as
+    one column gather, and the literal bytes; not once per field.
+
+    Optimization can drop a gather's scope name (op_name "gather"), so the
+    gathers one per output position (last dimension OUT_CAP) are counted
+    too: only the emit stage makes them."""
+    named, wide = [], []
+    for line in write_program.as_text().splitlines():
+        if " gather(" not in line:
+            continue
+        shape = re.search(r"= (\w+\[[\d,]*\])", line).group(1)
+        if "/lz4.emit/" in line:
+            named.append(shape)
+        if shape.endswith(f",{OUT_CAP}]"):
+            wide.append(shape)
+    want = sorted([f"s32[32,8,{OUT_CAP}]", f"s32[32,{OUT_CAP}]"])
+    assert sorted(named) == want
+    assert sorted(wide) == want
 
 
 @pytest.mark.parametrize("graph", ["decode_gather", "plan_decode"])
